@@ -2,7 +2,7 @@
 """Saturating multi-connection ingest load generator (ISSUE 10).
 
 Makes aggregate END-TO-END keys/sec a first-class tracked metric instead
-of "tunnel weather": a real subprocess server (so the measurement
+of an H2D-bound afterthought: a real subprocess server (so the measurement
 includes gRPC, decode, scheduling — everything a production client
 pays), one warm connection measured alone, then N concurrent
 connections hammering the same filter through the ingestion coalescer

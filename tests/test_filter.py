@@ -150,3 +150,16 @@ def test_big_m_virtual_34bit():
     f.insert_batch(keys)
     assert f.include_batch(keys).all()
     assert not f.include_batch([b"absent-%d" % i for i in range(100)]).any()
+
+
+@pytest.mark.parametrize("m", [1 << 20, 1 << 32, 1 << 36])
+def test_popcount_fill_takes_any_m(m):
+    """Stats and /metrics divide the set bits by m: an m of 2^31 or more
+    must not overflow JAX's int32 argument (it failed every /metrics
+    scrape at the north-star m=2^32)."""
+    import jax.numpy as jnp
+
+    from tpubloom.ops import bitops
+
+    bits = jnp.full((64, 128), 0xFFFFFFFF, jnp.uint32)
+    assert float(bitops.popcount_fill(bits, m)) == 64 * 128 * 32 / m

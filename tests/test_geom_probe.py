@@ -1,9 +1,10 @@
 """ISSUE 11 satellites: the geometry-probe machinery in
 :mod:`tpubloom.ops.sweep` — persistent on-disk cache keyed by device
 kind (a second process start performs ZERO speculative probe compiles),
-shape-identical probe buffers (ADVICE r5 #1), retry-once on transient
-compile failures (ADVICE r5 #2), failed probes never persisted, and the
-packed-KBJ bound on the validated-set fast path (ADVICE r5 #3).
+shape-identical probe buffers (ADVICE r5 #1), one compile per probe
+(a failure is real, not retried), failed probes never persisted, the
+packed-KBJ bound on the validated-set fast path (ADVICE r5 #3), and
+backend errors that propagate instead of reading as "not a TPU".
 
 All off-TPU: ``_probe_env`` / ``_probe_compile`` are the deliberate
 seams — the tests monkeypatch them so the cache/signature logic runs
@@ -155,21 +156,30 @@ def test_version_salt_invalidates_persisted_probes(fake_tpu, monkeypatch):
     )
 
 
-def test_probe_compile_retries_once_on_transient_failure():
-    """ADVICE r5 #2 (already shipping, pinned here): one transient
-    compile-service failure must not demote the geometry — the second
-    attempt lands."""
+def test_probe_compile_failure_is_not_retried():
+    """Compiles are local, so one failed probe compile is a real limit:
+    it is reported at once (no retry), with its cause."""
     state = {"n": 0}
 
-    def flaky(a, b, c):
+    def failing(a, b, c):
         state["n"] += 1
-        if state["n"] == 1:
-            raise RuntimeError("HTTP 500 from the compile service")
-        return a
+        raise RuntimeError("scoped VMEM exhausted")
 
     sds = jax.ShapeDtypeStruct((8, 128), jnp.uint32)
-    ok, exc = sweep._probe_compile(flaky, sds, sds, sds)
-    assert ok and state["n"] == 2
+    ok, exc = sweep._probe_compile(failing, sds, sds, sds)
+    assert not ok and state["n"] == 1
+    assert "scoped VMEM" in str(exc)
+
+
+def test_probe_env_propagates_backend_errors(monkeypatch):
+    """A backend that fails to initialise must not read as "not a TPU"
+    (that would skip every probe and hide the device)."""
+    def broken():
+        raise RuntimeError("TPU backend failed to initialise")
+
+    monkeypatch.setattr(sweep.jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="failed to initialise"):
+        sweep._probe_env()
 
 
 def test_validated_signature_bounds_packed_kbj(monkeypatch, tmp_path):

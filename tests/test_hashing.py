@@ -5,6 +5,8 @@ agree with each other and with published MurmurHash3_x86_32 / FNV-1a test
 vectors on every input hypothesis can dream up.
 """
 
+import os
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -145,3 +147,20 @@ def test_word_bit_split():
     word, bit = hashing.split_word_bit(ph, pl)
     assert int(word[0, 0]) == 37 >> 5 and int(bit[0, 0]) == 37 & 31
     assert int(word[0, 1]) == (1 << 27) | (37 >> 5)
+
+
+def test_native_library_is_keyed_on_source_and_flags(monkeypatch, tmp_path):
+    """The native library's name carries a hash of the committed source
+    and the compile flags: an edited source or changed flags name a new
+    library (rebuilt), never a stale one found by mtime."""
+    from tpubloom import native
+
+    path = native._lib_path()
+    assert os.path.basename(path).startswith("libbloomhash-")
+    monkeypatch.setattr(native, "_FLAGS", native._FLAGS + ("-DX=1",))
+    assert native._lib_path() != path
+    monkeypatch.undo()
+    src = tmp_path / "bloomhash.cpp"
+    src.write_bytes(open(native._SRC, "rb").read() + b"\n// edit\n")
+    monkeypatch.setattr(native, "_SRC", str(src))
+    assert os.path.basename(native._lib_path()) != os.path.basename(path)

@@ -302,6 +302,12 @@ def test_obs_smoke():
     import os
     import sys
 
+    from tpubloom.obs import blackbox
+
+    # the black box is process-global: an in-process replica applier
+    # from another module run earlier on this worker (test_ha's) leaves
+    # it armed, and the smoke measures the disabled default first
+    blackbox.reset_for_tests()
     sys.path.insert(
         0, os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
     )

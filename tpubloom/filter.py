@@ -20,6 +20,7 @@ TPU-first mechanics:
 
 from __future__ import annotations
 
+import logging
 import math
 from typing import Sequence
 
@@ -36,6 +37,8 @@ from tpubloom.utils.packing import (
     redis_bitmap_to_words,
     words_to_redis_bitmap,
 )
+
+log = logging.getLogger(__name__)
 
 
 def _pad_to_bucket(n: int, minimum: int = 64) -> int:
@@ -135,6 +138,17 @@ def blocked_device_shape(config: FilterConfig) -> tuple[int, int]:
     return (nb, w)
 
 
+def _log_path(op: str, path: str, config: FilterConfig, batch: int) -> str:
+    """Log the kernel path a blocked op resolved to. Called while jit
+    traces, so it prints once per (filter, batch shape) — on the first
+    launch of that shape; ``chip_smoke.py`` reads these lines."""
+    log.info(
+        "blocked %s path=%s batch=%d n_blocks=%d", op, path, batch,
+        config.n_blocks,
+    )
+    return path
+
+
 def make_blocked_insert_fn(config: FilterConfig, *, storage_fat: bool = False):
     """Pure ``(blocks[NB,W], keys_u8[B,L], lengths[B]) -> blocks`` insert for
     the blocked layout (ops.blocked spec).
@@ -151,7 +165,9 @@ def make_blocked_insert_fn(config: FilterConfig, *, storage_fat: bool = False):
     def insert(blocks, keys_u8, lengths):
         from tpubloom.ops import sweep
 
-        if sweep.resolve_insert_path(config, keys_u8.shape[0]) == "sweep":
+        B = keys_u8.shape[0]
+        path = sweep.resolve_insert_path(config, B)
+        if _log_path("insert", path, config, B) == "sweep":
             return sweep.make_sweep_insert_fn(config, storage_fat=storage_fat)(
                 blocks, keys_u8, lengths
             )
@@ -260,10 +276,9 @@ def make_blocked_test_insert_fn(config: FilterConfig, *, storage_fat: bool = Fal
     def test_insert(blocks, keys_u8, lengths):
         from tpubloom.ops import sweep
 
-        if (
-            sweep.resolve_insert_path(config, keys_u8.shape[0], presence=True)
-            == "sweep"
-        ):
+        B = keys_u8.shape[0]
+        path = sweep.resolve_insert_path(config, B, presence=True)
+        if _log_path("test_insert", path, config, B) == "sweep":
             return sweep.make_sweep_insert_fn(
                 config, with_presence=True, storage_fat=storage_fat
             )(blocks, keys_u8, lengths)
@@ -306,7 +321,9 @@ def make_blocked_query_fn(config: FilterConfig, *, storage_fat: bool = False):
         # effective (not just resolved) path: a forced "sweep" on a
         # shape the kernel cannot take demotes to the gather here —
         # served filters see arbitrary batch sizes
-        if sweep.effective_query_path(config, keys_u8.shape[0]) == "sweep":
+        B = keys_u8.shape[0]
+        path = sweep.effective_query_path(config, B)
+        if _log_path("query", path, config, B) == "sweep":
             return sweep.make_sweep_query_fn(config, storage_fat=storage_fat)(
                 blocks, keys_u8, lengths
             )

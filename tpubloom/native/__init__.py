@@ -9,6 +9,8 @@ the build is unavailable.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,7 +20,7 @@ from tpubloom.utils import locks
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "bloomhash.cpp")
-_LIB_PATH = os.path.join(_HERE, "libbloomhash.so")
+_FLAGS = ("-O3", "-march=native", "-shared", "-fPIC", "-std=c++17")
 
 _lock = locks.named_lock("native.build")
 _lib = None
@@ -26,16 +28,41 @@ _load_failed = False  # negative cache: never re-fork a failing compiler
 HAS_NATIVE = False
 
 
-def _build() -> bool:
-    cmd = [
-        "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-        _SRC, "-o", _LIB_PATH,
-    ]
+def _lib_path() -> str:
+    """Path of the library built from the committed source with
+    ``_FLAGS`` on this CPU: its name carries a hash of all three, so a
+    tree copied to another host (or holding an older build) never loads
+    a library made from other files or, under ``-march=native``, for
+    another CPU."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError):
+        with open("/proc/cpuinfo", "rb") as f:
+            h.update(next((ln for ln in f if ln.startswith(b"flags")), b""))
+    except OSError:
+        pass
+    return os.path.join(_HERE, f"libbloomhash-{h.hexdigest()[:16]}.so")
+
+
+def _build(path: str) -> bool:
+    tmp = f"{path}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["g++", *_FLAGS, _SRC, "-o", tmp],
+            check=True, capture_output=True, timeout=120,
+        )
+        os.replace(tmp, path)  # concurrent builders never tear the file
+    except (subprocess.SubprocessError, OSError):
         return False
+    for stale in glob.glob(os.path.join(_HERE, "libbloomhash*.so")):
+        if stale != path:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
+    return True
 
 
 def _load():
@@ -45,12 +72,12 @@ def _load():
             return _lib
         if _load_failed:
             return None
-        if not os.path.exists(_LIB_PATH) or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC):
-            if not _build():
-                _load_failed = True
-                return None
+        path = _lib_path()
+        if not os.path.exists(path) and not _build(path):
+            _load_failed = True
+            return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(path)
         except OSError:
             _load_failed = True
             return None

@@ -121,4 +121,5 @@ def popcount_fill(bits: jnp.ndarray, m: int) -> jnp.ndarray:
     """Fraction of set bits — drives estimated-FPR observability
     (fill^k ~ predicted FPR; SURVEY.md §5 metrics)."""
     set_bits = jnp.sum(jax.lax.population_count(bits).astype(jnp.float32))
-    return set_bits / m
+    # m as a float: an int m >= 2^31 overflows JAX's int32 argument
+    return set_bits / float(m)

@@ -974,12 +974,11 @@ _GEOM_PROBE_CACHE: dict = {}
 #: r5 #4): a cold start on an unvalidated TPU generation used to pay
 #: ~60 s of speculative Mosaic compiles — and every rolling restart of
 #: a fleet pays it again. Successful probes are written through to
-#: ``$TPUBLOOM_CACHE_DIR`` (default ``~/.cache/tpubloom``), keyed by
-#: device kind, so the second process start performs ZERO speculative
-#: probe compiles. Only ``ok=True`` results persist: a cached FAILURE
-#: would outlive the transient compile-service errors the in-process
-#: retry exists for, silently demoting every future process — a restart
-#: must stay the documented re-probe escape hatch.
+#: ``$TPUBLOOM_CACHE_DIR`` (default ``<checkout>/.jax_cache/geomprobe``,
+#: beside the compile cache), keyed by device kind, so the second
+#: process start performs ZERO speculative probe compiles. Only
+#: ``ok=True`` results persist: a failure demotes this process alone
+#: and is counted (``geometry_probe_demotions``).
 _GEOM_DISK_CACHE: dict = {}  # device kind -> set of ok key strings
 _GEOM_DISK_LOADED: set = set()  # device kinds whose file was read
 # (J, R8, S, KJP) tuples that compiled AND ran bit-exact on v5e
@@ -1010,21 +1009,22 @@ _VALIDATED_GEOMS = {
 def _probe_env():
     """Device kind when probe compiles apply (TPU backend), else None.
     The one seam between the probe machinery and the hardware — tests
-    monkeypatch it to exercise the cache off-TPU."""
-    try:
-        if jax.default_backend() != "tpu":
-            return None
-        return jax.devices()[0].device_kind
-    except Exception:
+    monkeypatch it to exercise the cache off-TPU. A backend that fails
+    to initialise raises here: reading that as "not a TPU" would skip
+    the probes and hide the device."""
+    if jax.default_backend() != "tpu":
         return None
+    return jax.devices()[0].device_kind
 
 
 def _geom_cache_path(kind: str) -> str:
     import os
     import re
 
+    from tpubloom.utils import compile_cache
+
     base = os.environ.get("TPUBLOOM_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "tpubloom"
+        compile_cache.CHECKOUT_CACHE_DIR, "geomprobe"
     )
     slug = re.sub(r"[^A-Za-z0-9._-]+", "-", kind)
     return os.path.join(base, f"geomprobe-{slug}.json")
@@ -1095,25 +1095,16 @@ def _geom_disk_put(kind: str, key_str: str) -> None:
 
 def _probe_compile(fn, blocks_sds, upd_sds, starts_sds):
     """One speculative Mosaic AOT compile (counted in
-    ``geometry_probe_compiles``), attempted TWICE before reporting
-    failure: this environment's compile service surfaces transient
-    failures (dropped connections, HTTP 500) as generic exceptions,
-    indistinguishable from a real Mosaic limit — and a cached False
-    silently demotes the process to slower shapes/scatter for its
-    lifetime (ADVICE r5 #2; bench.py retries the same failure mode). A
-    real scoped-VMEM OOM fails both attempts. Returns ``(ok, exc)``."""
+    ``geometry_probe_compiles``). Compiles are local, so a failure is a
+    real limit of the compiler for this shape. Returns ``(ok, exc)``."""
     from tpubloom.obs import counters as obs_counters
 
     obs_counters.incr("geometry_probe_compiles")
-    ok, last_exc = False, None
-    for _attempt in range(2):
-        try:
-            jax.jit(fn).lower(blocks_sds, upd_sds, starts_sds).compile()
-            ok = True
-            break
-        except Exception as e:  # noqa: BLE001 — any compile failure demotes
-            last_exc = e
-    return ok, last_exc
+    try:
+        jax.jit(fn).lower(blocks_sds, upd_sds, starts_sds).compile()
+    except Exception as e:  # noqa: BLE001 — any compile failure demotes
+        return False, e
+    return True, None
 
 
 _VALIDATED_KBJP_CAPS: dict = {}
@@ -1150,6 +1141,54 @@ def _validated_kbjp_cap(kind_name: str, sig) -> int:
     return cap
 
 
+def fat_kernel_shapes(
+    nb: int, w: int, geom, *, presence: bool = False, counting: bool = False,
+    query: bool = False, batch: int | None = None,
+):
+    """``(kernel, (blocks, upd, starts) ShapeDtypeStructs)`` for the fat
+    kernel the runtime launches at ``geom`` — what the geometry probe
+    compiles and what ``tests/test_tpu_compile.py`` compiles for a
+    described chip. With ``batch`` the update buffer carries the REAL
+    runtime row count (ADVICE r5 #1: the compile is then shape-identical
+    to the first real call)."""
+    J, R8, S, KJ, KBJ = geom
+    # pack must match the kernel the runtime will launch: both the
+    # chooser's volume bound and apply_fat_counter_updates use
+    # fat_pack(w, presence) — probing a pack=1 counting kernel would
+    # validate a strictly lighter scoped-VMEM footprint than the real
+    # PACK=4 unroll. The query kernel's stream carries the idx column
+    # like presence streams, so its pack matches presence's.
+    pk = fat_pack(w, presence or query)
+    kbjp = _packed_rows(KBJ, pk)
+    # update-stream rows exactly as _fat_stream will build them at
+    # runtime; probes with no batch at hand keep the legacy stand-in
+    if batch is None:
+        upd_rows = kbjp + 16
+    elif pk == 1:
+        upd_rows = int(batch) + KBJ + _ALIGN
+    else:
+        upd_rows = -(-int(batch) // pk) + kbjp + _ALIGN
+    NBJ = nb // J
+    blocks_sds = jax.ShapeDtypeStruct((NBJ, 128), jnp.uint32)
+    upd_sds = jax.ShapeDtypeStruct((upd_rows, 128), jnp.uint32)
+    starts_sds = jax.ShapeDtypeStruct((J * (NBJ // R8) + 1,), jnp.int32)
+    if counting:
+        fn = functools.partial(
+            fat_sweep_counter, J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w,
+            increment=True, pack=pk,
+        )
+    elif query:
+        fn = functools.partial(
+            fat_sweep_query, J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w, pack=pk,
+        )
+    else:
+        fn = functools.partial(
+            fat_sweep_insert, J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w,
+            with_presence=presence, pack=pk,
+        )
+    return fn, (blocks_sds, upd_sds, starts_sds)
+
+
 def _fat_geometry_compiles(
     nb: int, w: int, geom, *, presence: bool, counting: bool,
     query: bool = False, batch: int | None = None,
@@ -1174,13 +1213,7 @@ def _fat_geometry_compiles(
     if kind is None:
         return True
     J, R8, S, KJ, KBJ = geom
-    # pack must match the kernel the runtime will launch: both the
-    # chooser's volume bound and apply_fat_counter_updates use
-    # fat_pack(w, presence) — probing a pack=1 counting kernel would
-    # validate a strictly lighter scoped-VMEM footprint than the real
-    # PACK=4 unroll. The query kernel's stream carries the idx column
-    # like presence streams, so its pack matches presence's.
-    pk = fat_pack(w, presence or query)
+    pk = fat_pack(w, presence or query)  # as fat_kernel_shapes packs
     kbjp = _packed_rows(KBJ, pk)
     if any(v in kind for v in _VALIDATED_DEVICE_KINDS):
         if not (presence or counting or query):
@@ -1196,15 +1229,14 @@ def _fat_geometry_compiles(
         # (ISSUE 12 ships the kernel; the first TPU round will grow one)
         # — every query shape probe-compiles, on v5e too, and the result
         # persists in the on-disk cache like any other probe.
-    # update-stream rows exactly as _fat_stream will build them at
-    # runtime; probes with no batch at hand keep the legacy stand-in
-    if batch is None:
-        upd_rows = kbjp + 16
-    elif pk == 1:
-        upd_rows = int(batch) + KBJ + _ALIGN
-    else:
-        upd_rows = -(-int(batch) // pk) + kbjp + _ALIGN
-    key = (kind, nb, w, J, R8, S, KJ, KBJ, presence, counting, query, upd_rows)
+    fn, (blocks_sds, upd_sds, starts_sds) = fat_kernel_shapes(
+        nb, w, geom, presence=presence, counting=counting, query=query,
+        batch=batch,
+    )
+    key = (
+        kind, nb, w, J, R8, S, KJ, KBJ, presence, counting, query,
+        upd_sds.shape[0],
+    )
     hit = _GEOM_PROBE_CACHE.get(key)
     if hit is not None:
         return hit
@@ -1212,24 +1244,6 @@ def _fat_geometry_compiles(
     if _geom_disk_get(kind, key_str):
         _GEOM_PROBE_CACHE[key] = True
         return True
-    NBJ = nb // J
-    blocks_sds = jax.ShapeDtypeStruct((NBJ, 128), jnp.uint32)
-    upd_sds = jax.ShapeDtypeStruct((upd_rows, 128), jnp.uint32)
-    starts_sds = jax.ShapeDtypeStruct((J * (NBJ // R8) + 1,), jnp.int32)
-    if counting:
-        fn = functools.partial(
-            fat_sweep_counter, J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w,
-            increment=True, pack=pk,
-        )
-    elif query:
-        fn = functools.partial(
-            fat_sweep_query, J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w, pack=pk,
-        )
-    else:
-        fn = functools.partial(
-            fat_sweep_insert, J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w,
-            with_presence=presence, pack=pk,
-        )
     ok, last_exc = _probe_compile(fn, blocks_sds, upd_sds, starts_sds)
     if not ok:
         import warnings
@@ -1242,13 +1256,9 @@ def _fat_geometry_compiles(
         obs_counters.incr("geometry_probe_demotions")
         warnings.warn(
             f"tpubloom: fat-sweep geometry {geom} failed its probe "
-            f"compile twice on device kind {kind!r}; this geometry is "
+            f"compile on device kind {kind!r}; this geometry is "
             f"disabled for the process (falling back to the next "
-            f"shape / scatter path). NOTE: the probe cannot tell a "
-            f"real Mosaic limit from a persistent compile-service "
-            f"error — restart the process to re-probe (failures are "
-            f"deliberately NOT written to the on-disk probe cache). "
-            f"Cause: {str(last_exc)[:300]}",
+            f"shape / scatter path). Cause: {str(last_exc)[:300]}",
             RuntimeWarning,
             stacklevel=2,
         )

@@ -32,23 +32,14 @@ driver's ``dryrun_multichip``.
 
 from __future__ import annotations
 
+import logging
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6: experimental namespace + check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, **kwargs):
-        if "check_vma" in kwargs:  # renamed from check_rep in newer jax
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_legacy(f, **kwargs)
-
-
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpubloom import faults
@@ -59,6 +50,8 @@ from tpubloom.ops import bitops, blocked, counting, hashing
 from tpubloom.utils.packing import redis_bitmap_to_words, words_to_redis_bitmap
 
 AXIS = "shards"
+
+log = logging.getLogger(__name__)
 
 
 def make_mesh(n_shards: int, devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
@@ -660,6 +653,11 @@ class ShardedBloomFilter(_FilterBase):
                 make_sharded_insert_fn(config, self.mesh), donate_argnums=0
             )
             self._query = jax.jit(make_sharded_query_fn(config, self.mesh))
+        for shard in self.words.addressable_shards:
+            rows = range(config.shards)[shard.index[0]]
+            log.info(
+                "shard rows %d-%d on %s", rows[0], rows[-1], shard.device
+            )
 
     def clear(self) -> None:
         self.words = jax.device_put(jnp.zeros_like(self.words), self.sharding)

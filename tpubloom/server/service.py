@@ -3201,6 +3201,16 @@ def main(argv: Optional[list] = None) -> None:
         (lambda config: ckpt.FileSink(ckpt_dir)) if ckpt_dir else (lambda config: None)
     )
     logging.basicConfig(level=logging.INFO)
+    import jax
+
+    from tpubloom.utils import compile_cache
+
+    log.info("compile cache: %s", compile_cache.configure())
+    devices = jax.devices()
+    log.info(
+        "jax devices: platform=%s kind=%s count=%d",
+        devices[0].platform, devices[0].device_kind, len(devices),
+    )
     faults.load_env()
     for armed in faults.active():
         log.warning("fault injection armed: %s", armed)

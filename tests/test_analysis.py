@@ -570,6 +570,51 @@ def test_phase_registry_reverse_check(tmp_path):
     assert "'ghost_phase'" in phase_findings[0].message
 
 
+def test_histogram_registry_closes_both_directions(tmp_path):
+    """Tree mode: the histogram families the exposition renders are
+    exactly ``names.HISTOGRAMS`` — a declared family nobody renders and
+    a rendered family nobody declared are both findings."""
+    pkg = tmp_path / "tpubloom" / "obs"
+    pkg.mkdir(parents=True)
+    (pkg / "names.py").write_text(
+        'HISTOGRAMS = ("rpc_duration_seconds", "ghost_seconds")\n'
+    )
+    (pkg / "exposition.py").write_text(
+        "def render(out, met, bounds):\n"
+        '    _render_histogram(out, "rpc_duration_seconds", [], bounds, "x")\n'
+        '    _render_histogram(out, "stray_seconds", [], bounds, "y")\n'
+    )
+    findings = L.check_histogram_registry(str(tmp_path))
+    assert _checks(findings) == ["metric-registry"] * 2
+    text = " ".join(f.message for f in findings)
+    assert "'ghost_seconds'" in text and "'stray_seconds'" in text
+    # a tree without the declaration (partial fixtures) is not checked
+    (pkg / "names.py").write_text('COUNTERS = ("x",)\n')
+    assert L.check_histogram_registry(str(tmp_path)) == []
+
+
+def test_timed_hold_counts_as_holding_the_lock(tmp_path):
+    """``with _timed_hold(x.lock):`` (the ingest flush's timed acquire)
+    is a lock region like ``with x.lock:``."""
+    findings = _lint_source(
+        tmp_path,
+        """
+        import os
+
+        class S:
+            def bad_io(self, mf):
+                with _timed_hold(mf.lock):
+                    os.fsync(3)
+
+            def ok(self, mf):
+                with _timed_hold(mf.lock):
+                    pass
+                os.fsync(3)
+        """,
+    )
+    assert _checks(findings) == ["blocking-under-lock"]
+
+
 # -- static lint: the suppression grammar --------------------------------------
 
 

@@ -39,7 +39,8 @@ Checks
     ``metrics.count`` / ``counters.set_gauge`` is declared in
     :mod:`tpubloom.obs.names` under the right kind; (tree mode) every
     declared name is emitted at least once, and no name is declared
-    twice or under both kinds.
+    twice or under both kinds; the histogram families
+    ``obs/exposition.py`` renders are exactly ``names.HISTOGRAMS``.
 
 ``protocol-coverage``
     (tree mode) Every ``protocol.METHODS`` entry has a ``BloomService``
@@ -335,8 +336,20 @@ class _Suppressions:
 # -- per-file checkers --------------------------------------------------------
 
 
+#: helpers whose ``with helper(x.lock):`` holds ``x.lock`` for the
+#: block (the ingest flush's timed acquire) — read as ``with x.lock:``.
+LOCK_HOLDERS = frozenset({"_timed_hold"})
+
+
 def _is_lock_with_item(item: ast.withitem) -> Optional[str]:
     ctx = item.context_expr
+    if (
+        isinstance(ctx, ast.Call)
+        and isinstance(ctx.func, ast.Name)
+        and ctx.func.id in LOCK_HOLDERS
+        and ctx.args
+    ):
+        ctx = ctx.args[0]
     if isinstance(ctx, ast.Attribute) and ctx.attr in LOCK_ATTRS:
         return _dotted(ctx)
     return None
@@ -1042,6 +1055,53 @@ def _literal_set(path: str) -> set:
     }
 
 
+def check_histogram_registry(repo_root: str) -> list:
+    """(tree mode, ``metric-registry``) The family names
+    ``obs/exposition.py`` passes to ``_render_histogram`` as literals
+    equal :data:`tpubloom.obs.names.HISTOGRAMS`, in both directions.
+    Skipped when either file, or the declaration, is absent (partial
+    fixture trees)."""
+    names_path = os.path.join(repo_root, "tpubloom", "obs", "names.py")
+    expo_path = os.path.join(repo_root, "tpubloom", "obs", "exposition.py")
+    if not (os.path.isfile(names_path) and os.path.isfile(expo_path)):
+        return []
+    declared = _parse_string_collection_lines(
+        names_path, ("HISTOGRAMS",)
+    ).get("HISTOGRAMS")
+    if declared is None:
+        return []
+    with open(expo_path, "r", encoding="utf-8") as f:
+        tree = ast.parse(f.read(), filename=expo_path)
+    rendered: dict = {}
+    for n in ast.walk(tree):
+        if (
+            isinstance(n, ast.Call)
+            and isinstance(n.func, ast.Name)
+            and n.func.id == "_render_histogram"
+            and len(n.args) >= 2
+            and isinstance(n.args[1], ast.Constant)
+            and isinstance(n.args[1].value, str)
+        ):
+            rendered.setdefault(n.args[1].value, n.lineno)
+    findings = []
+    for name, line in declared:
+        if name not in rendered:
+            findings.append(Finding(
+                "metric-registry", names_path, line,
+                f"declared histogram {name!r} is never rendered by "
+                f"obs/exposition.py — stale catalog entry",
+            ))
+    names = {name for name, _ in declared}
+    for name, line in sorted(rendered.items()):
+        if name not in names:
+            findings.append(Finding(
+                "metric-registry", expo_path, line,
+                f"histogram {name!r} is rendered but not declared in "
+                f"tpubloom.obs.names.HISTOGRAMS",
+            ))
+    return findings
+
+
 def _service_handlers(path: str) -> tuple:
     """(method defs on BloomService, keys of the stream behavior maps)."""
     with open(path, "r", encoding="utf-8") as f:
@@ -1429,6 +1489,7 @@ def lint_paths(paths: Iterable[str], config: Optional[LintConfig] = None) -> lis
         tree_findings.extend(check_ruby_parity(repo_root))
         tree_findings.extend(check_replay_safety(repo_root))
         tree_findings.extend(check_chaos_coverage(repo_root))
+        tree_findings.extend(check_histogram_registry(repo_root))
         for point in sorted(config.known_fault_points - fault_literal_seen):
             tree_findings.append(
                 Finding(

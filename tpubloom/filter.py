@@ -459,7 +459,7 @@ class _FilterBase:
         key_len) — the zero-copy decode path of the ``fixed`` wire
         encoding."""
         out = self.launch_insert(self.stage_batch(rows=rows))
-        if obs.current() is not None:
+        if obs.fencing():
             # same honesty fence as insert_batch: under an active
             # request the kernel phase must cover real device work
             with obs.phase("kernel"):
@@ -469,7 +469,7 @@ class _FilterBase:
     def include_packed(self, rows: np.ndarray) -> np.ndarray:
         """Membership for fixed-width pre-packed keys."""
         hits, B = self.launch_query(self.stage_batch(rows=rows))
-        if obs.current() is not None:
+        if obs.fencing():
             with obs.phase("kernel_query"):
                 self._kernel_fence(hits)
         with obs.phase("d2h"):
@@ -506,7 +506,7 @@ class _FilterBase:
         keys_u8, lengths = self._stage_batch(keys_u8, lengths)
         with obs.phase("kernel"):
             self.words = self._insert(self.words, keys_u8, lengths)
-            if obs.current() is not None:
+            if obs.fencing():
                 # fence so the kernel phase covers real device work, not
                 # just async dispatch; only under an active request (the
                 # library/streaming path keeps JAX's async pipelining).
@@ -523,7 +523,7 @@ class _FilterBase:
         self._query_launch_counter(keys_u8.shape[0])
         with obs.phase("kernel_query"):
             hits = self._query(self.words, keys_u8, lengths)
-            if obs.current() is not None:
+            if obs.fencing():
                 self._kernel_fence(hits)
         with obs.phase("d2h"):
             out = np.asarray(hits)
@@ -701,7 +701,7 @@ class BlockedBloomFilter(_FilterBase):
         keys_u8, lengths = self._stage_batch(keys_u8, lengths)
         with obs.phase("kernel"):
             self.words, present = self._test_insert(self.words, keys_u8, lengths)
-            if obs.current() is not None:
+            if obs.fencing():
                 self._kernel_fence(present)
         with obs.phase("d2h"):
             out = np.asarray(present)

@@ -10,9 +10,12 @@ without attaching a profiler or running bench archaeology.
 Pieces (each importable on its own, stdlib-only except where noted):
 
 * :mod:`tpubloom.obs.context` — thread-local request context + named
-  phase timers (decode / host_prep / h2d / kernel / d2h / encode). The
-  filter layer records phases into whatever request is active; with no
-  active request every span is a no-op, so library users pay ~nothing.
+  phase timers (decode / host_prep / h2d / kernel / d2h / encode, and
+  the coalescer's flush steps). The filter layer records phases into
+  whatever request is active; with no active request every span is a
+  no-op, so library users pay ~nothing. Under a context each phase is
+  also a ``tpubloom.<phase>`` ``jax.profiler`` host span (the one
+  non-stdlib import here).
 * :mod:`tpubloom.obs.counters` — process-global counters for events that
   happen below the server layer (e.g. ``geometry_probe_demotions`` when a
   Pallas geometry probe demotes to scatter), merged into ``/metrics``.
@@ -35,13 +38,17 @@ Pieces (each importable on its own, stdlib-only except where noted):
 Request correlation: the gRPC client stamps every request with a ``rid``
 (``BloomClient.last_rid``); the server threads it into
 ``tracing.annotate`` spans AND the slowlog entry, so a slow request found
-in SLOWLOG can be looked up by id in a Perfetto trace of the same window.
+in SLOWLOG can be looked up by id in a Perfetto trace of the same window
+— on the direct path. A coalesced request's work runs in a flush that
+serves many rids; its steps appear as ``tpubloom.<phase>`` spans on the
+dispatcher thread and in the ``ingest_flush_phase_seconds`` histograms.
 """
 
 from tpubloom.obs.context import (  # noqa: F401
     RequestContext,
     current,
     current_rid,
+    fencing,
     new_rid,
     phase,
     request,

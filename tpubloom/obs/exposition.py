@@ -15,6 +15,12 @@ Metric catalog (all prefixed ``tpubloom_``):
   1us..~67s), labels ``{method}``.
 * ``rpc_phase_seconds`` — the phase breakdown histogram, labels
   ``{method, phase}`` for decode/host_prep/h2d/kernel/d2h/encode.
+* ``ingest_flush_phase_seconds`` / ``ingest_flush_phase_cpu_seconds``
+  — the ingest coalescer's flush steps, wall and dispatcher-thread CPU
+  time, labels ``{op, phase}``; ``ingest_park_wait_seconds{op}`` — a
+  request's wait parked before its flush; ``ingest_dispatch_idle_seconds``
+  — the dispatcher's waits with nothing ripe. Every histogram family is
+  declared in ``tpubloom.obs.names.HISTOGRAMS``.
 * ``filter_fill_ratio`` / ``filter_bits_set`` / ``filter_estimated_fpr``
   / ``filter_predicted_fpr`` / ``filter_fpr_drift`` /
   ``filter_keys_inserted`` / ``filter_keys_queried`` /
@@ -248,6 +254,44 @@ def render_service(service, *, exemplars: bool = False) -> str:
             [({}, hydrations)],
             bounds,
             "Tenant hydration latency (storage paging fault, ISSUE 14)",
+        )
+
+    def by_op_phase(hists: dict):
+        for key, h in sorted(hists.items()):
+            op, _, phase_name = key.partition("/")
+            yield {"op": op, "phase": phase_name}, h
+
+    _render_histogram(
+        out,
+        "ingest_flush_phase_seconds",
+        by_op_phase(met.get("flush_phases") or {}),
+        bounds,
+        "Wall time of one ingest coalescer flush step, per flush",
+    )
+    _render_histogram(
+        out,
+        "ingest_flush_phase_cpu_seconds",
+        by_op_phase(met.get("flush_phases_cpu") or {}),
+        bounds,
+        "Dispatcher thread CPU time of one flush step, per flush",
+    )
+    _render_histogram(
+        out,
+        "ingest_park_wait_seconds",
+        (({"op": op}, h) for op, h in sorted(
+            (met.get("park_waits") or {}).items()
+        )),
+        bounds,
+        "Time a coalesced request waited parked before its flush",
+    )
+    idle = met.get("dispatch_idle")
+    if idle and idle.get("n"):
+        _render_histogram(
+            out,
+            "ingest_dispatch_idle_seconds",
+            [({}, idle)],
+            bounds,
+            "One ingest dispatcher wait with nothing ripe to flush",
         )
 
     gauge_headers_done: set[str] = set()

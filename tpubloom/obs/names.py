@@ -175,6 +175,11 @@ GAUGES = (
 #: slowlog, ``bench.py``'s ``e2e_phases`` tail and the per-phase
 #: latency histograms keep naming the same stages. Semantics are
 #: documented where the spans are minted: :mod:`tpubloom.obs.context`.
+#: The request phases (decode .. encode) land in ``rpc_phase_seconds``;
+#: the coalescer's flush steps (merge .. demux, with the filter phases
+#: a flush runs) in the ``ingest_flush_phase_*`` histograms, and
+#: ``dispatch_idle`` in ``ingest_dispatch_idle_seconds`` (HISTOGRAMS).
+#: Each is also a ``tpubloom.<phase>`` profiler host span.
 PHASES = (
     "decode",
     "host_prep",
@@ -183,6 +188,13 @@ PHASES = (
     "kernel_query",
     "d2h",
     "encode",
+    "merge",
+    "unpack",
+    "lock_wait",
+    "settle",
+    "log",
+    "demux",
+    "dispatch_idle",
 )
 
 #: Phase names minted at runtime, prefix-declared like the metric
@@ -309,6 +321,35 @@ DYNAMIC_PREFIXES = (
     ("cluster_slot_keys_total_", "counter",
      "per-slot key traffic on keyed RPCs (service wrapper, cluster "
      "mode) — the load signal slot rebalancing should follow"),
+)
+
+#: Histogram families ``/metrics`` renders (``tpubloom_<name>``, log2
+#: buckets 1us..~67s; :mod:`tpubloom.server.metrics` files them,
+#: :mod:`tpubloom.obs.exposition` renders them). Semantics:
+#:
+#: * ``rpc_duration_seconds{method}`` — end-to-end RPC latency
+#: * ``rpc_phase_seconds{method,phase}`` — the request phases of one RPC
+#: * ``wait_barrier_seconds`` — time blocked on replica acks
+#: * ``storage_hydration_seconds`` — one tenant hydration
+#: * ``ingest_flush_phase_seconds{op,phase}`` — wall time of one
+#:   coalescer flush step, one observation per flush per phase it ran
+#:   (op: insert / query / delete / clear)
+#: * ``ingest_flush_phase_cpu_seconds{op,phase}`` — the dispatcher
+#:   thread's CPU time over the same observations; wall minus CPU is
+#:   time blocked on the GIL, a lock or the device
+#: * ``ingest_park_wait_seconds{op}`` — one coalesced request's wait
+#:   from park to the pop of its group for a flush
+#: * ``ingest_dispatch_idle_seconds`` — one dispatcher wait with
+#:   nothing ripe
+HISTOGRAMS = (
+    "rpc_duration_seconds",
+    "rpc_phase_seconds",
+    "wait_barrier_seconds",
+    "storage_hydration_seconds",
+    "ingest_flush_phase_seconds",
+    "ingest_flush_phase_cpu_seconds",
+    "ingest_park_wait_seconds",
+    "ingest_dispatch_idle_seconds",
 )
 
 COUNTER_SET = frozenset(COUNTERS)

@@ -62,6 +62,7 @@ behavior; the FPR model accounts for both.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -89,7 +90,17 @@ def block_positions(
     ``bit`` uint32[..., k], in-block bit positions. ``block_hash`` selects
     the in-block variant ("chunk" / "ap"); callers with a FilterConfig
     must pass ``config.block_hash`` — it is part of the filter identity.
+    Traced under the hash stage's named scope (``hashing.HASH_SCOPE``).
     """
+    with jax.named_scope(hashing.HASH_SCOPE):
+        return _block_positions(
+            keys, lengths, n_blocks=n_blocks, block_bits=block_bits, k=k,
+            seed=seed, block_hash=block_hash,
+        )
+
+
+def _block_positions(keys, lengths, *, n_blocks, block_bits, k, seed,
+                     block_hash):
     h_a = hashing.murmur3_32(keys, lengths, seed)
     g_a = hashing.fnv1a_32(keys, lengths)
     g_b = hashing.murmur3_32(keys, lengths, seed ^ hashing.SEED_XOR_GB)

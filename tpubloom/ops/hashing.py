@@ -41,6 +41,7 @@ have no u64 — via k-step iterative addition with carry, which is exactly
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -62,6 +63,13 @@ SEED_XOR_GB = 0x85EBCA6B
 # Independent of the position hashes so routing doesn't correlate with
 # within-shard positions.
 SEED_XOR_ROUTE = 0x517CC1B7
+
+
+#: ``jax.named_scope`` of the hash stage of every filter program (the
+#: sweep stages are ``tpubloom/sort``, ``/sweep`` and ``/unsort``, in
+#: :mod:`tpubloom.ops.sweep`): the device ops it emits carry it in their
+#: HLO ``op_name`` metadata, so a profile can group them by stage.
+HASH_SCOPE = "tpubloom/hash"
 
 
 def _u32(x) -> jnp.ndarray:
@@ -178,10 +186,12 @@ def positions(
       For m <= 2^32, pos_hi is all zeros.
     """
     if (m & (m - 1)) == 0:
-        return _positions_pow2(keys, lengths, m=m, k=k, seed=seed)
+        with jax.named_scope(HASH_SCOPE):
+            return _positions_pow2(keys, lengths, m=m, k=k, seed=seed)
     if m >= (1 << 31):
         raise ValueError("non-power-of-two m must be < 2^31")
-    return _positions_mod(keys, lengths, m=m, k=k, seed=seed)
+    with jax.named_scope(HASH_SCOPE):
+        return _positions_mod(keys, lengths, m=m, k=k, seed=seed)
 
 
 def _positions_pow2(keys, lengths, *, m: int, k: int, seed: int):

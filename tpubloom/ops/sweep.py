@@ -64,6 +64,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpubloom.ops import blocked
 
+#: ``jax.named_scope`` names of the sweep programs' stages after the
+#: hash (``tpubloom.ops.hashing.HASH_SCOPE``): sorting keys by owning block and building the
+#: update stream; the partition-sweep kernel (or its fallback); and
+#: putting presence verdicts back in key order. Each device op carries
+#: its stage in the HLO ``op_name`` metadata.
+SORT_SCOPE = "tpubloom/sort"
+SWEEP_SCOPE = "tpubloom/sweep"
+UNSORT_SCOPE = "tpubloom/unsort"
+
 
 class InFlight:
     """Depth-1 host-side double buffer (ISSUE 10).
@@ -914,15 +923,21 @@ def apply_blocked_updates(
         )
     P = nb // R
     interp = jax.default_backend() == "cpu" if interpret is None else interpret
-    blk = jnp.where(valid, blk, nb)
-    cols, nbits, packed = _pack_positions(bit, block_bits, k)
-    sorted_cols = lax.sort((blk,) + cols, num_keys=1)
-    bs = sorted_cols[0]
-    bit_sorted = _unpack_positions(sorted_cols[1:], block_bits, k, nbits, packed)
-    masks = blocked.build_masks(bit_sorted, w)
-    starts, upd = _stream_scaffold(bs, nb, P, R, KMAX)
-    upd = upd.at[:B, 1 : w + 1].set(masks)
-    return sweep_insert(blocks, upd, starts, R=R, KMAX=KMAX, interpret=interp)
+    with jax.named_scope(SORT_SCOPE):
+        blk = jnp.where(valid, blk, nb)
+        cols, nbits, packed = _pack_positions(bit, block_bits, k)
+        sorted_cols = lax.sort((blk,) + cols, num_keys=1)
+        bs = sorted_cols[0]
+        bit_sorted = _unpack_positions(
+            sorted_cols[1:], block_bits, k, nbits, packed
+        )
+        masks = blocked.build_masks(bit_sorted, w)
+        starts, upd = _stream_scaffold(bs, nb, P, R, KMAX)
+        upd = upd.at[:B, 1 : w + 1].set(masks)
+    with jax.named_scope(SWEEP_SCOPE):
+        return sweep_insert(
+            blocks, upd, starts, R=R, KMAX=KMAX, interpret=interp
+        )
 
 
 # =========================================================================
@@ -1900,28 +1915,29 @@ def apply_fat_updates(
     NBJ = nb // J
     P8 = NBJ // R8
     interp = jax.default_backend() == "cpu" if interpret is None else interpret
-    blkv = jnp.where(valid, blk, nb)
-    j_of = (blkv % J).astype(jnp.uint32)
-    rf_of = (blkv // J).astype(jnp.uint32)
-    skey = jnp.where(valid, j_of * NBJ + rf_of, _u32(J * NBJ))
-    cols, nbits, packed = _pack_positions(bit, block_bits, bit.shape[-1])
-    extra = (idx,) if idx is not None else ()
-    sorted_cols = lax.sort((skey,) + cols + extra, num_keys=1)
-    ss = sorted_cols[0]
-    pcols = sorted_cols[1:-1] if idx is not None else sorted_cols[1:]
-    bit_sorted = _unpack_positions(
-        pcols, block_bits, bit.shape[-1], nbits, packed
-    )
-    masks = blocked.build_masks(bit_sorted, w)
-    idx_sorted = sorted_cols[-1] if idx is not None else None
     pack = fat_pack(w, idx is not None)
-    upd, starts = _fat_stream(
-        ss, masks, idx_sorted, J=J, NBJ=NBJ, P8=P8, R8=R8, KBJ=KBJ, W=w,
-        pack=pack,
-    )
-    overflow = _fat_window_overflow(
-        starts, J=J, P8=P8, S=S, KJ=KJ, KBJ=KBJ, pack=pack
-    )
+    with jax.named_scope(SORT_SCOPE):
+        blkv = jnp.where(valid, blk, nb)
+        j_of = (blkv % J).astype(jnp.uint32)
+        rf_of = (blkv // J).astype(jnp.uint32)
+        skey = jnp.where(valid, j_of * NBJ + rf_of, _u32(J * NBJ))
+        cols, nbits, packed = _pack_positions(bit, block_bits, bit.shape[-1])
+        extra = (idx,) if idx is not None else ()
+        sorted_cols = lax.sort((skey,) + cols + extra, num_keys=1)
+        ss = sorted_cols[0]
+        pcols = sorted_cols[1:-1] if idx is not None else sorted_cols[1:]
+        bit_sorted = _unpack_positions(
+            pcols, block_bits, bit.shape[-1], nbits, packed
+        )
+        masks = blocked.build_masks(bit_sorted, w)
+        idx_sorted = sorted_cols[-1] if idx is not None else None
+        upd, starts = _fat_stream(
+            ss, masks, idx_sorted, J=J, NBJ=NBJ, P8=P8, R8=R8, KBJ=KBJ, W=w,
+            pack=pack,
+        )
+        overflow = _fat_window_overflow(
+            starts, J=J, P8=P8, S=S, KJ=KJ, KBJ=KBJ, pack=pack
+        )
 
     def to_fat(bl):
         return bl if storage_fat else bl.reshape(NBJ, 128)
@@ -1940,6 +1956,7 @@ def apply_fat_updates(
 
     if idx is None:
 
+        @jax.named_scope(SWEEP_SCOPE)
         def fat_branch(ops):
             bl, u, st = ops
             return from_fat(
@@ -1950,6 +1967,7 @@ def apply_fat_updates(
                 )
             )
 
+        @jax.named_scope(SWEEP_SCOPE)
         def scatter_branch(ops):
             bl, u, st = ops
             row, masks_orig = _scatter_coords()
@@ -1959,17 +1977,20 @@ def apply_fat_updates(
 
     def fat_branch(ops):
         bl, u, st = ops
-        new_fat, presb = fat_sweep_insert(
-            to_fat(bl), u, st,
-            J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w,
-            interpret=interp, with_presence=True, pack=pack,
-        )
-        present = _fat_unsort_presence(
-            presb, st, B, J=J, NBJ=NBJ, P8=P8, R8=R8, S=S,
-            KJ=pack * _packed_rows(KJ, pack), KBJ=KBJ,
-        )
+        with jax.named_scope(SWEEP_SCOPE):
+            new_fat, presb = fat_sweep_insert(
+                to_fat(bl), u, st,
+                J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w,
+                interpret=interp, with_presence=True, pack=pack,
+            )
+        with jax.named_scope(UNSORT_SCOPE):
+            present = _fat_unsort_presence(
+                presb, st, B, J=J, NBJ=NBJ, P8=P8, R8=R8, S=S,
+                KJ=pack * _packed_rows(KJ, pack), KBJ=KBJ,
+            )
         return from_fat(new_fat), present
 
+    @jax.named_scope(SWEEP_SCOPE)
     def scatter_branch(ops):
         bl, u, st = ops
         row, masks_orig = _scatter_coords()
@@ -2344,21 +2365,24 @@ def make_sweep_insert_fn(
             )
         if storage_fat:
             blocks = blocks.reshape(nb, w)
-        blk = jnp.where(valid, blk, nb)
-        cols, nbits, packed = _pack_positions(bit, bb, k)
-        idx0 = jnp.arange(1, B + 1, dtype=jnp.uint32)  # 0 = filler
-        cols = cols + (idx0,)
-        sorted_cols = lax.sort((blk,) + cols, num_keys=1)
-        bs = sorted_cols[0]
-        bit_sorted = _unpack_positions(sorted_cols[1:-1], bb, k, nbits, packed)
-        masks = blocked.build_masks(bit_sorted, w)
-        # sentinel rows carry zero masks (their positions are real hash
-        # bits of padding keys; they never reach a partition, but keep
-        # the invariant obvious)
-        starts, upd = _stream_scaffold(bs, nb, P, R, KMAX)
-        upd = upd.at[:B, 1 : w + 1].set(masks)
+        with jax.named_scope(SORT_SCOPE):
+            blk = jnp.where(valid, blk, nb)
+            cols, nbits, packed = _pack_positions(bit, bb, k)
+            idx0 = jnp.arange(1, B + 1, dtype=jnp.uint32)  # 0 = filler
+            cols = cols + (idx0,)
+            sorted_cols = lax.sort((blk,) + cols, num_keys=1)
+            bs = sorted_cols[0]
+            bit_sorted = _unpack_positions(
+                sorted_cols[1:-1], bb, k, nbits, packed
+            )
+            masks = blocked.build_masks(bit_sorted, w)
+            # sentinel rows carry zero masks (their positions are real
+            # hash bits of padding keys; they never reach a partition,
+            # but keep the invariant obvious)
+            starts, upd = _stream_scaffold(bs, nb, P, R, KMAX)
+            upd = upd.at[:B, 1 : w + 1].set(masks)
 
-        upd = upd.at[:B, w + 1].set(sorted_cols[-1])
+            upd = upd.at[:B, w + 1].set(sorted_cols[-1])
         # chunk-0 windows cover [align8(starts[p]), +KMAX); a partition
         # whose slice exceeds that emits no presence for the overflow —
         # rare (KMAX covers lambda+8sigma; needs adversarial duplicate
@@ -2373,27 +2397,30 @@ def make_sweep_insert_fn(
             hit = jnp.all((rows & masks_orig) == masks_orig, axis=-1)
             return hit & valid & (blk < nb)
 
-        presence_fb = lax.cond(
-            overflow,
-            gather_presence,
-            lambda: jnp.zeros((B,), bool),
-        )
-        new_blocks, pres_packed = sweep_insert(
-            blocks, upd, starts,
-            R=R, KMAX=KMAX, interpret=interp, with_presence=True,
-        )
-        v = pres_packed.reshape(P, 8, KMAX // 8).transpose(0, 2, 1).reshape(-1)
-        # single-column unsort: key = (idx+1) << 1 | hit sorts by original
-        # index with the verdict riding the LSB; filler slots (v == 0) map
-        # to the max key and sink to the tail
-        vkey = jnp.where(
-            v == 0,
-            _u32(0xFFFFFFFE),  # even: filler slots must read as hit=0
-            ((v & _u32(0x7FFFFFFF)) << _u32(1)) | (v >> _u32(31)),
-        )
-        (skey,) = lax.sort((vkey,), num_keys=1)
-        fused = (skey[:B] & _u32(1)) == 1
-        present = jnp.where(overflow, presence_fb, fused)
+        with jax.named_scope(SWEEP_SCOPE):
+            presence_fb = lax.cond(
+                overflow,
+                gather_presence,
+                lambda: jnp.zeros((B,), bool),
+            )
+            new_blocks, pres_packed = sweep_insert(
+                blocks, upd, starts,
+                R=R, KMAX=KMAX, interpret=interp, with_presence=True,
+            )
+        with jax.named_scope(UNSORT_SCOPE):
+            v = pres_packed.reshape(P, 8, KMAX // 8).transpose(0, 2, 1)
+            v = v.reshape(-1)
+            # single-column unsort: key = (idx+1) << 1 | hit sorts by
+            # original index with the verdict riding the LSB; filler
+            # slots (v == 0) map to the max key and sink to the tail
+            vkey = jnp.where(
+                v == 0,
+                _u32(0xFFFFFFFE),  # even: filler slots must read as hit=0
+                ((v & _u32(0x7FFFFFFF)) << _u32(1)) | (v >> _u32(31)),
+            )
+            (skey,) = lax.sort((vkey,), num_keys=1)
+            fused = (skey[:B] & _u32(1)) == 1
+            present = jnp.where(overflow, presence_fb, fused)
         if storage_fat:
             new_blocks = new_blocks.reshape(fat_shape)
         return new_blocks, present
@@ -2845,40 +2872,44 @@ def apply_fat_query(
     NBJ = nb // J
     P8 = NBJ // R8
     interp = jax.default_backend() == "cpu" if interpret is None else interpret
-    blkv = jnp.where(valid, blk, nb)
-    j_of = (blkv % J).astype(jnp.uint32)
-    rf_of = (blkv // J).astype(jnp.uint32)
-    skey = jnp.where(valid, j_of * NBJ + rf_of, _u32(J * NBJ))
-    cols, nbits, packed = _pack_positions(bit, block_bits, bit.shape[-1])
-    idx0 = jnp.arange(1, B + 1, dtype=jnp.uint32)  # 0 = empty slot
-    sorted_cols = lax.sort((skey,) + cols + (idx0,), num_keys=1)
-    ss = sorted_cols[0]
-    bit_sorted = _unpack_positions(
-        sorted_cols[1:-1], block_bits, bit.shape[-1], nbits, packed
-    )
-    masks = blocked.build_masks(bit_sorted, w)
-    idx_sorted = sorted_cols[-1]
     pack = fat_pack(w, True)
-    upd, starts = _fat_stream(
-        ss, masks, idx_sorted, J=J, NBJ=NBJ, P8=P8, R8=R8, KBJ=KBJ, W=w,
-        pack=pack,
-    )
-    overflow = _fat_window_overflow(
-        starts, J=J, P8=P8, S=S, KJ=KJ, KBJ=KBJ, pack=pack
-    )
+    with jax.named_scope(SORT_SCOPE):
+        blkv = jnp.where(valid, blk, nb)
+        j_of = (blkv % J).astype(jnp.uint32)
+        rf_of = (blkv // J).astype(jnp.uint32)
+        skey = jnp.where(valid, j_of * NBJ + rf_of, _u32(J * NBJ))
+        cols, nbits, packed = _pack_positions(bit, block_bits, bit.shape[-1])
+        idx0 = jnp.arange(1, B + 1, dtype=jnp.uint32)  # 0 = empty slot
+        sorted_cols = lax.sort((skey,) + cols + (idx0,), num_keys=1)
+        ss = sorted_cols[0]
+        bit_sorted = _unpack_positions(
+            sorted_cols[1:-1], block_bits, bit.shape[-1], nbits, packed
+        )
+        masks = blocked.build_masks(bit_sorted, w)
+        idx_sorted = sorted_cols[-1]
+        upd, starts = _fat_stream(
+            ss, masks, idx_sorted, J=J, NBJ=NBJ, P8=P8, R8=R8, KBJ=KBJ, W=w,
+            pack=pack,
+        )
+        overflow = _fat_window_overflow(
+            starts, J=J, P8=P8, S=S, KJ=KJ, KBJ=KBJ, pack=pack
+        )
 
     def sweep_branch(ops):
         bl, u, st = ops
-        presb = fat_sweep_query(
-            bl if storage_fat else bl.reshape(NBJ, 128), u, st,
-            J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w, interpret=interp,
-            pack=pack,
-        )
-        return _fat_unsort_presence(
-            presb, st, B, J=J, NBJ=NBJ, P8=P8, R8=R8, S=S,
-            KJ=pack * _packed_rows(KJ, pack), KBJ=KBJ,
-        )
+        with jax.named_scope(SWEEP_SCOPE):
+            presb = fat_sweep_query(
+                bl if storage_fat else bl.reshape(NBJ, 128), u, st,
+                J=J, R8=R8, S=S, KJ=KJ, KBJ=KBJ, W=w, interpret=interp,
+                pack=pack,
+            )
+        with jax.named_scope(UNSORT_SCOPE):
+            return _fat_unsort_presence(
+                presb, st, B, J=J, NBJ=NBJ, P8=P8, R8=R8, S=S,
+                KJ=pack * _packed_rows(KJ, pack), KBJ=KBJ,
+            )
 
+    @jax.named_scope(SWEEP_SCOPE)
     def gather_branch(ops):
         bl, u, st = ops
         masks_orig = blocked.build_masks(bit, w)

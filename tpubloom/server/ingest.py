@@ -50,6 +50,19 @@ kernel runs, the dispatcher stages the next flush's host_prep/H2D, then
 fences the previous flush and completes its waiters — the host feed and
 the device overlap instead of ping-ponging.
 
+Instrumentation: every flush runs under a request context of its own
+(:func:`tpubloom.obs.context.request`), so each step is an
+``obs.phase`` — ``merge``, ``unpack``, ``lock_wait``, the filter's
+``host_prep``/``h2d``/``kernel``/``kernel_query``/``d2h``, ``settle``,
+``log``, ``demux`` — and the dispatcher's wait for work is
+``dispatch_idle``. Each phase is a ``tpubloom.<phase>`` profiler span
+and, per flush, one observation of wall and dispatcher CPU time in the
+``ingest_flush_phase_seconds`` / ``ingest_flush_phase_cpu_seconds``
+histograms; each request's wait parked is one observation of
+``ingest_park_wait_seconds``. An untraced flush's context is a
+background one: its launches stay unfenced and its op-log record
+carries no rid, as when flushes ran under no context.
+
 Fault points: ``ingest.coalesce`` fires in ``submit`` before a request
 parks (nothing applied — safe to retry); ``ingest.flush`` fires in the
 dispatcher before a flush applies (ditto).
@@ -61,6 +74,7 @@ the dispatcher drops it before touching any filter/registry/log lock.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -83,6 +97,19 @@ class _EvictedRace(Exception):
     """The flush's resolved ``_Managed`` was paged out between lookup
     and lock (ISSUE 14) — the dispatcher re-resolves (hydrating if
     needed) and retries the flush against the live filter."""
+
+
+@contextlib.contextmanager
+def _timed_hold(lock):
+    """Hold ``lock`` for the block; the wait to take it is the flush's
+    ``lock_wait`` phase. The lint treats ``with _timed_hold(x.lock):``
+    as ``with x.lock:``."""
+    with obs.phase("lock_wait"):
+        lock.acquire()
+    try:
+        yield
+    finally:
+        lock.release()
 
 
 def _check_live(mf) -> None:
@@ -386,6 +413,14 @@ class IngestCoalescer:
 
     def _run(self) -> None:
         self._in_dispatch.active = True
+        # the loop's own context times what runs outside any flush: the
+        # idle wait and settling a parked launch; filed every turn
+        with obs.request(
+            "ingest.dispatch", background=True, thread_cpu=True
+        ) as loop:
+            self._dispatch(loop)
+
+    def _dispatch(self, loop) -> None:
         stopping = False
         while not stopping:
             with self._cond:
@@ -397,10 +432,11 @@ class IngestCoalescer:
                         # nothing ripe and nothing in flight: sleep
                         # until the oldest entry's deadline or a submit
                         timeout = self._wait_locked()
-                        self._cond.wait(
-                            timeout=1.0 if timeout is None
-                            else max(timeout, 0.0005)
-                        )
+                        with obs.phase("dispatch_idle"):
+                            self._cond.wait(
+                                timeout=1.0 if timeout is None
+                                else max(timeout, 0.0005)
+                            )
                         batch = self._pop_ripe_locked()
                 if batch is not None:
                     self._flushing += 1
@@ -408,8 +444,13 @@ class IngestCoalescer:
                 # the gap gave the in-flight kernel its overlap window —
                 # fence it and complete its waiters (outside all locks)
                 self.flush_inflight()
+                self._file_loop(loop)
                 continue
-            (name, kind), entries = batch
+            (name, kind), entries, popped_at = batch
+            self._service.metrics.observe_park_waits(
+                kind, [popped_at - e.enq_t for e in entries]
+            )
+            self._file_loop(loop)
             try:
                 self._flush(name, kind, entries)
             except BaseException as e:  # noqa: BLE001 — waiters must wake
@@ -430,6 +471,24 @@ class IngestCoalescer:
                     self._flushing -= 1
                     self._cond.notify_all()
         self.flush_inflight()
+        self._file_loop(loop)
+
+    def _file_loop(self, loop) -> None:
+        """File what the dispatcher loop's context timed since the last
+        turn: its idle wait, and the settle (with the settled flush's
+        demux) of an insert launch parked across the gap, which joins
+        the insert flushes' phases."""
+        if not loop.phases:
+            return
+        metrics = self._service.metrics
+        idle = loop.phases.pop("dispatch_idle", None)
+        loop.phases_cpu.pop("dispatch_idle", None)
+        if idle is not None:
+            metrics.observe_dispatch_idle(idle)
+        if loop.phases:
+            metrics.observe_flush("insert", loop.phases, loop.phases_cpu)
+        loop.phases.clear()
+        loop.phases_cpu.clear()
 
     def _wait_locked(self) -> Optional[float]:
         """Seconds until the oldest parked entry ripens (None = idle)."""
@@ -443,7 +502,8 @@ class IngestCoalescer:
         )
 
     def _pop_ripe_locked(self):
-        """Pop the ripest group (size/bytes/deadline), or None."""
+        """Pop the ripest group (size/bytes/deadline) as ``(key,
+        entries, monotonic time of the pop)``, or None."""
         now = time.monotonic()
         ripe_key = None
         for key, entries in self._groups.items():
@@ -465,46 +525,59 @@ class IngestCoalescer:
         entries = self._groups.pop(ripe_key)
         self._parked_keys -= sum(e.nkeys for e in entries)
         obs_counters.set_gauge("ingest_parked_current", self._parked_keys)
-        return ripe_key, entries
+        return ripe_key, entries, now
 
     # -- flush ---------------------------------------------------------------
 
     def _flush(self, name: str, kind: str, entries: list) -> None:
-        """One flush, optionally traced (ISSUE 15): when any parked
-        request is captured, the flush runs under ITS OWN trace id —
+        """One flush, under a request context of its own: its steps
+        time into the context's phases, filed once per flush under
+        ``op=kind``. Untraced, the context is a background one — no
+        rid, no fences — so the launches and the merged op-log record
+        are what they are without it. When any parked request is
+        captured, the same context is armed under ITS OWN trace id:
         the ``ingest.flush`` root span LINKS every traced request's
-        root span, the request context it opens turns the kernel
-        phases (host_prep/h2d/kernel) into the flush span's children,
-        and the merged op-log record is minted under the flush rid
-        (``_log_op`` reads ``obs.current_rid()``), so replica applies
-        of the merged record join the same trace. Untraced flushes
-        take the exact pre-ISSUE-15 path."""
+        root span, the phases become the flush span's children, and the
+        merged op-log record is minted under the flush rid (``_log_op``
+        reads ``obs.current_rid()``), so replica applies of the merged
+        record join the same trace."""
         refs = [e.trace for e in entries if e.trace is not None]
-        if not (obs_trace.enabled() and refs):
-            return self._flush_inner(name, kind, entries, None)
-        frid = obs.new_rid()
-        froot = obs_trace.new_span_id()
-        with obs.request(f"ingest.{kind}", rid=frid) as rctx:
-            rctx.trace_armed = True
-            rctx.trace_span = froot
+        traced = obs_trace.enabled() and bool(refs)
+        nkeys = int(sum(e.nkeys for e in entries))
+        with obs.request(
+            f"ingest.{kind}",
+            rid=obs.new_rid() if traced else None,
+            background=not traced,
+            thread_cpu=True,
+        ) as rctx:
+            rctx.batch = nkeys
+            ftrace = None
+            if traced:
+                rctx.trace_armed = True
+                rctx.trace_span = obs_trace.new_span_id()
+                ftrace = (rctx.rid, rctx.trace_span)
             try:
-                return self._flush_inner(name, kind, entries, (frid, froot))
+                self._flush_inner(name, kind, entries, ftrace)
             finally:
-                obs_trace.record_span(
-                    "ingest.flush",
-                    rid=frid,
-                    span=froot,
-                    start=rctx.started_at,
-                    duration_s=max(0.0, time.time() - rctx.started_at),
-                    attrs={
-                        "filter": name,
-                        "op": kind,
-                        "requests": len(entries),
-                        "keys": int(sum(e.nkeys for e in entries)),
-                    },
-                    links=[{"rid": r, "span": s} for r, s in refs],
+                self._service.metrics.observe_flush(
+                    kind, rctx.phases, rctx.phases_cpu
                 )
-                obs_trace.commit_children(rctx, froot)
+                if ftrace is not None:
+                    obs_trace.record_span(
+                        "ingest.flush",
+                        rid=ftrace[0],
+                        span=ftrace[1],
+                        start=rctx.started_at,
+                        duration_s=max(0.0, time.time() - rctx.started_at),
+                        attrs={
+                            "filter": name,
+                            "op": kind,
+                            "requests": len(entries),
+                            "keys": nkeys,
+                        },
+                        links=[{"rid": r, "span": s} for r, s in refs],
+                    )
+                    obs_trace.commit_children(rctx, ftrace[1])
 
     def _flush_inner(
         self, name: str, kind: str, entries: list, ftrace
@@ -621,6 +694,23 @@ class IngestCoalescer:
         if parts:
             logged["parts"] = parts
 
+    def _log_merged(self, method: str, name: str, mf, rows, keys,
+                    entries: list) -> Optional[int]:
+        """ONE op-log append for a merged insert/delete flush: the
+        merged keys (the raw buffer when fixed-width) and the
+        replay-unsafe parts; returns the record's seq."""
+        logged: dict = {"name": name}
+        if rows is not None:
+            logged["keys_fixed"] = {
+                "data": rows.tobytes(),
+                "width": int(rows.shape[1]),
+                "n": int(rows.shape[0]),
+            }
+        else:
+            logged["keys"] = keys
+        self._log_parts(logged, entries)
+        return self._service._log_op(method, logged, mf)
+
     @staticmethod
     def _demote_wide_rows(mf, rows, keys):
         """Fixed-width keys WIDER than the filter's key_len cannot take
@@ -652,39 +742,41 @@ class IngestCoalescer:
         return None, merged
 
     def _flush_query(self, mf, entries: list) -> None:
-        rows, keys = self._demote_wide_rows(mf, *self._merge(entries))
+        with obs.phase("merge"):
+            rows, keys = self._demote_wide_rows(mf, *self._merge(entries))
         # stage OUTSIDE the op lock where the filter supports it — the
         # host prep/H2D of this flush overlaps the previous flush's
         # in-flight kernel (double buffering, ISSUE 10)
         staged = None
         if self._service._staged_ok(mf):
             staged = mf.filter.stage_batch(keys, rows=rows)
-        with mf.lock:
+        with _timed_hold(mf.lock):
             _check_live(mf)
             if staged is not None:
                 hits_dev, _ = mf.filter.launch_query(staged)
-                hits = np.asarray(hits_dev)  # fence + D2H
+                with obs.phase("d2h"):
+                    hits = np.asarray(hits_dev)  # fence + D2H
             else:
                 hits = np.asarray(
-                    mf.filter.include_batch(
-                        keys if keys is not None else _rows_to_list(rows)
-                    )
+                    mf.filter.include_batch(_key_list(keys, rows))
                 )
         self._service.metrics.count("keys_queried", sum(e.nkeys for e in entries))
-        off = 0
-        for entry in entries:
-            span = hits[off: off + entry.nkeys]
-            off += entry.nkeys
-            entry.complete(resp={
-                "ok": True,
-                "hits": np.packbits(span).tobytes(),
-                "n": entry.nkeys,
-                "_coalesced": True,
-            })
+        with obs.phase("demux"):
+            off = 0
+            for entry in entries:
+                span = hits[off: off + entry.nkeys]
+                off += entry.nkeys
+                entry.complete(resp={
+                    "ok": True,
+                    "hits": np.packbits(span).tobytes(),
+                    "n": entry.nkeys,
+                    "_coalesced": True,
+                })
 
     def _flush_insert(self, name: str, mf, entries: list, ftrace=None) -> None:
         service = self._service
-        rows, keys = self._demote_wide_rows(mf, *self._merge(entries))
+        with obs.phase("merge"):
+            rows, keys = self._demote_wide_rows(mf, *self._merge(entries))
         want_presence = any(e.want_presence for e in entries)
         supports_staged = not want_presence and service._staged_ok(mf)
         staged = (
@@ -694,9 +786,9 @@ class IngestCoalescer:
         # launch — its kernel had our whole staging window to run, and a
         # barrier-bearing completion hops to the completer thread, so
         # neither blocks the dispatcher.
-        self._settle(*self._inflight.take())
+        self._settle(*self._take_inflight())
         presence = None
-        with mf.lock:
+        with _timed_hold(mf.lock):
             _check_live(mf)
             if service.cluster is not None and (
                 service.cluster.forward_target(name) is not None
@@ -713,7 +805,7 @@ class IngestCoalescer:
                 if staged is not None:
                     out = mf.filter.launch_insert(staged)
                 elif want_presence:
-                    klist = keys if keys is not None else _rows_to_list(rows)
+                    klist = _key_list(keys, rows)
                     if mf.supports_presence:
                         presence = mf.filter.insert_batch(
                             klist, return_presence=True
@@ -723,8 +815,7 @@ class IngestCoalescer:
                         mf.filter.insert_batch(klist)
                     out = None
                 else:
-                    klist = keys if keys is not None else _rows_to_list(rows)
-                    mf.filter.insert_batch(klist)
+                    mf.filter.insert_batch(_key_list(keys, rows))
                     out = None
                 # honest-FULL verdicts (ISSUE 19): cuckoo inserts can
                 # reject; collect the per-key flags under the lock —
@@ -744,21 +835,14 @@ class IngestCoalescer:
                             out = None  # already fenced by the flag read
                 # ONE op-log append covers the whole flush (log before
                 # notify — the PR-3 ordering rule)
-                logged: dict = {"name": name}
-                if rows is not None:
-                    logged["keys_fixed"] = {
-                        "data": rows.tobytes(),
-                        "width": int(rows.shape[1]),
-                        "n": int(rows.shape[0]),
-                    }
-                else:
-                    logged["keys"] = keys
-                self._log_parts(logged, entries)
-                seq = service._log_op("InsertBatch", logged, mf)
-                if mf.checkpointer:
-                    mf.checkpointer.notify_inserts(
-                        sum(e.nkeys for e in entries)
+                with obs.phase("log"):
+                    seq = self._log_merged(
+                        "InsertBatch", name, mf, rows, keys, entries
                     )
+                    if mf.checkpointer:
+                        mf.checkpointer.notify_inserts(
+                            sum(e.nkeys for e in entries)
+                        )
         if fallback:
             self._fallback_direct(entries)
             return
@@ -766,7 +850,8 @@ class IngestCoalescer:
             "keys_inserted", sum(e.nkeys for e in entries)
         )
         if presence is not None:
-            presence = np.asarray(presence)  # fence + D2H, outside the lock
+            with obs.phase("d2h"):
+                presence = np.asarray(presence)  # fence + D2H, outside the lock
 
         def finalize():
             self._finalize_insert(entries, seq, presence, ftrace, full=full)
@@ -788,12 +873,13 @@ class IngestCoalescer:
         applies), so every entry's demuxed response is dedup-cached
         under its rid by the shared finalize."""
         service = self._service
-        rows, keys = self._demote_wide_rows(mf, *self._merge(entries))
+        with obs.phase("merge"):
+            rows, keys = self._demote_wide_rows(mf, *self._merge(entries))
         # fence + settle any in-flight insert flush BEFORE the (donating)
         # delete launch consumes its output buffer — a real kernel error
         # must fail the INSERT's waiters, not surface as this delete's
-        self._settle(*self._inflight.take())
-        with mf.lock:
+        self._settle(*self._take_inflight())
+        with _timed_hold(mf.lock):
             _check_live(mf)
             if service.cluster is not None and (
                 service.cluster.forward_target(name) is not None
@@ -803,26 +889,19 @@ class IngestCoalescer:
                 fallback = True
             else:
                 fallback = False
-                klist = keys if keys is not None else _rows_to_list(rows)
-                dout = mf.filter.delete_batch(klist)
+                dout = mf.filter.delete_batch(_key_list(keys, rows))
                 deleted = None
                 if dout is not None and sketch_registry.is_sketch(
                     mf.filter.config
                 ):
                     # cuckoo per-key "a stored copy existed" verdicts,
                     # demuxed back to each parked request like presence
-                    deleted = np.asarray(dout, dtype=bool)
-                logged: dict = {"name": name}
-                if rows is not None:
-                    logged["keys_fixed"] = {
-                        "data": rows.tobytes(),
-                        "width": int(rows.shape[1]),
-                        "n": int(rows.shape[0]),
-                    }
-                else:
-                    logged["keys"] = keys
-                self._log_parts(logged, entries)
-                seq = service._log_op("DeleteBatch", logged, mf)
+                    with obs.phase("d2h"):
+                        deleted = np.asarray(dout, dtype=bool)
+                with obs.phase("log"):
+                    seq = self._log_merged(
+                        "DeleteBatch", name, mf, rows, keys, entries
+                    )
         if fallback:
             self._fallback_direct(entries, method="DeleteBatch")
             return
@@ -839,8 +918,8 @@ class IngestCoalescer:
         idempotent, so N concurrent clears ARE one clear — no dedup
         caching needed and no per-request payload to demux)."""
         service = self._service
-        self._settle(*self._inflight.take())  # see _flush_delete
-        with mf.lock:
+        self._settle(*self._take_inflight())  # see _flush_delete
+        with _timed_hold(mf.lock):
             _check_live(mf)
             if service.cluster is not None and (
                 service.cluster.forward_target(name) is not None
@@ -849,7 +928,8 @@ class IngestCoalescer:
             else:
                 fallback = False
                 mf.filter.clear()
-                seq = service._log_op("Clear", {"name": name}, mf)
+                with obs.phase("log"):
+                    seq = service._log_op("Clear", {"name": name}, mf)
         if fallback:
             self._fallback_direct(entries, method="Clear")
             return
@@ -908,10 +988,18 @@ class IngestCoalescer:
                     self._completing -= 1
                     self._cond.notify_all()
 
+    def _take_inflight(self):
+        """Fence the parked double-buffered launch (the ``settle``
+        phase) and return its ``(payload, fence error)``."""
+        with obs.phase("settle"):
+            return self._inflight.take()
+
     def flush_inflight(self) -> None:
         """Fence + settle any parked double-buffered flush (dispatcher
         thread only — the run loop calls this when the queues go idle)."""
-        payload, err = self._inflight.take()
+        if not self._inflight.pending:
+            return
+        payload, err = self._take_inflight()
         if payload is None:
             return
         self._settle(payload, err)
@@ -931,9 +1019,11 @@ class IngestCoalescer:
         from tpubloom.server import protocol
 
         try:
-            self._finalize_insert_inner(
-                entries, seq, presence, ftrace, full=full, deleted=deleted
-            )
+            with obs.phase("demux"):
+                self._finalize_insert_inner(
+                    entries, seq, presence, ftrace, full=full,
+                    deleted=deleted,
+                )
         except BaseException as e:  # noqa: BLE001 — waiters must wake
             log.exception("ingest finalize failed")
             err = (
@@ -1094,6 +1184,16 @@ def _keys_of(entry: _Entry) -> list:
     if entry.keys is not None:
         return list(entry.keys)
     return _rows_to_list(entry.rows)
+
+
+def _key_list(keys, rows) -> list:
+    """A flush's merged keys as a list: ``keys`` when the merge made
+    one, else the fixed-width ``rows`` turned back into ``bytes`` (the
+    flush's ``unpack`` phase)."""
+    if keys is not None:
+        return keys
+    with obs.phase("unpack"):
+        return _rows_to_list(rows)
 
 
 def _rows_to_list(rows: np.ndarray) -> list:

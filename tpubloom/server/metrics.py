@@ -10,7 +10,9 @@ This module holds the in-process numbers; :mod:`tpubloom.obs.exposition`
 renders them as a Prometheus scrape and :mod:`tpubloom.obs.slowlog` keeps
 the per-request tail. ``Metrics.observe_rpc`` also files the per-phase
 breakdown (decode/host_prep/h2d/kernel/d2h/encode) the request context
-collected, keyed ``"<method>/<phase>"``.
+collected, keyed ``"<method>/<phase>"``; ``observe_flush``,
+``observe_park_waits`` and ``observe_dispatch_idle`` file the ingest
+coalescer's dispatcher the same way, keyed ``"<op>/<phase>"``.
 """
 
 from __future__ import annotations
@@ -112,6 +114,19 @@ class Metrics:
         #: multiplexing more tenants than HBM holds, and the number the
         #: --max-resident-bytes sizing runbook is calibrated against
         self.hydrations = LatencyHistogram()
+        #: the ingest coalescer's dispatcher thread: "<op>/<phase>" ->
+        #: wall and thread-CPU time of one flush step (one observation
+        #: per flush per phase), per-op park waits, and idle waits
+        self.flush_phases: dict[str, LatencyHistogram] = defaultdict(
+            LatencyHistogram
+        )
+        self.flush_phases_cpu: dict[str, LatencyHistogram] = defaultdict(
+            LatencyHistogram
+        )
+        self.park_waits: dict[str, LatencyHistogram] = defaultdict(
+            LatencyHistogram
+        )
+        self.dispatch_idle = LatencyHistogram()
         self.started_at = time.time()
 
     def count(self, name: str, n: int = 1) -> None:
@@ -147,6 +162,29 @@ class Metrics:
         with self._lock:
             self.hydrations.observe(seconds)
 
+    def observe_flush(
+        self, op: str, phases: dict, cpu: Optional[dict] = None
+    ) -> None:
+        """File one coalescer flush's phase breakdown: wall seconds and,
+        when kept, the dispatcher thread's CPU seconds per phase."""
+        with self._lock:
+            for name, seconds in phases.items():
+                self.flush_phases[f"{op}/{name}"].observe(seconds)
+            for name, seconds in (cpu or {}).items():
+                self.flush_phases_cpu[f"{op}/{name}"].observe(seconds)
+
+    def observe_park_waits(self, op: str, waits: list) -> None:
+        """File how long each request of one flush waited parked."""
+        with self._lock:
+            hist = self.park_waits[op]
+            for seconds in waits:
+                hist.observe(seconds)
+
+    def observe_dispatch_idle(self, seconds: float) -> None:
+        """File one dispatcher wait with nothing ripe."""
+        with self._lock:
+            self.dispatch_idle.observe(seconds)
+
     def snapshot(self) -> dict:
         from tpubloom.obs import counters as global_counters
 
@@ -172,4 +210,14 @@ class Metrics:
                 "phases": {k: v.export() for k, v in self.phases.items()},
                 "waits": self.waits.export(),
                 "hydrations": self.hydrations.export(),
+                "flush_phases": {
+                    k: v.export() for k, v in self.flush_phases.items()
+                },
+                "flush_phases_cpu": {
+                    k: v.export() for k, v in self.flush_phases_cpu.items()
+                },
+                "park_waits": {
+                    k: v.export() for k, v in self.park_waits.items()
+                },
+                "dispatch_idle": self.dispatch_idle.export(),
             }

@@ -94,7 +94,7 @@ class CountMinSketch(_FilterBase):
 
     def insert_batch(self, keys: Sequence[bytes | str]) -> None:
         out = self.launch_insert(self.stage_batch(keys))
-        if obs.current() is not None:
+        if obs.fencing():
             with obs.phase("kernel"):
                 self._kernel_fence(out)
 
@@ -126,7 +126,7 @@ class CountMinSketch(_FilterBase):
             self.words = self._incr(
                 self.words, d_keys, d_lengths, jnp.asarray(padded)
             )
-            if obs.current() is not None:
+            if obs.fencing():
                 self._kernel_fence(self.words)
         self.n_inserted += B
         self._post_update(d_keys, d_lengths, B)
@@ -148,7 +148,7 @@ class CountMinSketch(_FilterBase):
         d_keys, d_lengths = self._stage_batch(keys_u8, lengths)
         with obs.phase("kernel_query"):
             est = self._estimate(self.words, d_keys, d_lengths)
-            if obs.current() is not None:
+            if obs.fencing():
                 self._kernel_fence(est)
         with obs.phase("d2h"):
             out = np.asarray(est)

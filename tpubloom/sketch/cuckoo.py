@@ -101,7 +101,7 @@ class CuckooFilter(_FilterBase):
 
     def insert_batch(self, keys: Sequence[bytes | str]) -> None:
         out = self.launch_insert(self.stage_batch(keys))
-        if obs.current() is not None:
+        if obs.fencing():
             with obs.phase("kernel"):
                 self._kernel_fence(out)
 
@@ -140,7 +140,7 @@ class CuckooFilter(_FilterBase):
         d_keys, d_lengths = self._stage_batch(keys_u8, lengths)
         with obs.phase("kernel"):
             self.words, deleted = self._delete(self.words, d_keys, d_lengths)
-            if obs.current() is not None:
+            if obs.fencing():
                 self._kernel_fence(self.words)
         with obs.phase("d2h"):
             out = np.asarray(deleted)

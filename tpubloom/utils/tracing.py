@@ -18,11 +18,14 @@ Usage::
     # or one-shot around a callable:
     result, trace_dir = tracing.profile_call(fn, *args)
 
-The gRPC server wires ``request_span`` around every request so
-per-request spans appear in device traces (tpubloom/server/service.py)
-carrying the client-generated request id — the same id the slowlog entry
-records (``tpubloom.obs.slowlog``), so "find slowlog entry rid=X, open
-the trace, search rid=X" is the triage loop.
+The gRPC server wires ``request_span`` around every request it serves on
+the direct (uncoalesced) path, so those spans appear in device traces
+(tpubloom/server/service.py) carrying the client-generated request id —
+the same id the slowlog entry records (``tpubloom.obs.slowlog``), so
+"find slowlog entry rid=X, open the trace, search rid=X" is the triage
+loop. Coalesced requests have no such span: their work runs in a flush
+on the coalescer's dispatcher thread, whose steps appear as the
+``tpubloom.<phase>`` spans :func:`tpubloom.obs.context.phase` enters.
 """
 
 from __future__ import annotations
@@ -52,16 +55,26 @@ def trace(log_dir: str, *, create: bool = True) -> Iterator[str]:
         jax.profiler.stop_trace()
 
 
+def host_span(name: str, **meta: Any) -> jax.profiler.TraceAnnotation:
+    """A host span in the profiler timeline: a TraceMe named ``name``
+    with ``meta`` as keyword metadata (stats on the event, not part of
+    its name). Entering it costs well under a microsecond while no
+    profiler trace is running. The one helper behind :func:`annotate`
+    and the ``obs.context`` phase timers."""
+    return jax.profiler.TraceAnnotation(name, **meta)
+
+
 @contextlib.contextmanager
 def annotate(name: str, **attrs: Any) -> Iterator[None]:
-    """Named span in the profiler timeline (TraceAnnotation).
+    """Named span in the profiler timeline (:func:`host_span`).
 
-    ``attrs`` are folded into the span name (TraceAnnotation carries no
-    structured payload) — keep them short, e.g. ``batch=4096``.
+    ``attrs`` are folded into the span name, so that a timeline search
+    for ``rid=<id>`` finds the span — keep them short, e.g.
+    ``batch=4096``.
     """
     if attrs:
         name = name + "[" + ",".join(f"{k}={v}" for k, v in attrs.items()) + "]"
-    with jax.profiler.TraceAnnotation(name):
+    with host_span(name):
         yield
 
 

@@ -23,14 +23,25 @@ bits, and a key is present when all of its k bits are. With ``s`` and
 A verdict outside those bounds is wrong. The check runs on a sample of
 verdicts drawn from the seed (``check_sample`` in the mix) and, for each
 sampled key, on every inserted key that shares its block, so it is
-exact for the keys it samples.
+exact for the keys it samples. Finding those inserted keys means
+regenerating and hashing every key an insert RPC carried, which grows
+with the keys served; that pass runs on one worker process per usable
+host core, over contiguous shards of the insert RPCs.
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
+import json
+import multiprocessing
+import os
+import tempfile
+from concurrent.futures import ProcessPoolExecutor
 
 import numpy as np
+
+import traffic
 
 SEED_XOR_HB = 0x9E3779B9
 SEED_XOR_GB = 0x85EBCA6B
@@ -39,6 +50,7 @@ _C1 = np.uint32(0xCC9E2D51)
 _C2 = np.uint32(0x1B873593)
 CHUNK_KEYS = 1 << 20  # inserted keys hashed per step of the check
 MASK_BITS = 1 << 29  # the check's prefilter of bit ids: 64 MiB
+COLUMNS = ("stream", "i", "n", "rpc", "send", "ack")  # of the insert RPCs, as the check's workers get them
 
 
 def _rotl32(x, r):
@@ -48,14 +60,22 @@ def _rotl32(x, r):
 def murmur3_32(keys: np.ndarray, lengths: np.ndarray, seed: int) -> np.ndarray:
     """MurmurHash3_x86_32 of zero-padded keys ``uint8[B, L]``."""
     lengths = np.asarray(lengths, np.int32)
-    kb = np.asarray(keys, np.uint8).astype(np.uint32)
-    blocks = kb[:, 0::4] | (kb[:, 1::4] << 8) | (kb[:, 2::4] << 16) | (kb[:, 3::4] << 24)
+    keys = np.asarray(keys, np.uint8)
+    if keys.shape[1] % 4 == 0:
+        blocks = np.ascontiguousarray(keys).view("<u4").astype(np.uint32)
+    else:
+        kb = keys.astype(np.uint32)
+        blocks = kb[:, 0::4] | (kb[:, 1::4] << 8) | (kb[:, 2::4] << 16) | (kb[:, 3::4] << 24)
+    shortest = int(lengths.min()) if len(lengths) else 0
     h = np.full(lengths.shape, np.uint32(seed), np.uint32)
     with np.errstate(over="ignore"):
         for i in range(keys.shape[1] // 4):
             kk = _rotl32(blocks[:, i] * _C1, 15) * _C2
-            rem = lengths - 4 * i
             full = _rotl32(h ^ kk, 13) * np.uint32(5) + np.uint32(0xE6546B64)
+            if shortest >= 4 * (i + 1):  # every key fills this word
+                h = full
+                continue
+            rem = lengths - 4 * i
             h = np.where(rem >= 4, full, np.where(rem > 0, h ^ kk, h))
         h = h ^ lengths.astype(np.uint32)
         h ^= h >> np.uint32(16)
@@ -112,6 +132,7 @@ class Spec:
     """The position spec of one filter configuration."""
 
     def __init__(self, cfg: dict):
+        self.cfg = dict(cfg)
         self.k, self.seed = int(cfg["k"]), int(cfg["seed"])
         self.key_len, self.key_policy = int(cfg["key_len"]), cfg.get("key_policy", "error")
         self.block_bits, self.shards = int(cfg["block_bits"]), int(cfg.get("shards", 1))
@@ -153,10 +174,32 @@ class Spec:
         return self.block(ku8, lens)[:, None] * self.block_bits + self.bits(ku8, lens)
 
 
+class _IdSet:
+    """Sorted bit ids, with a bitmap of them modulo MASK_BITS: most other
+    bits are dropped by it before the exact search."""
+
+    def __init__(self, ids: np.ndarray):
+        self.ids = ids
+        low = ids & (MASK_BITS - 1)
+        self.mask = np.zeros(MASK_BITS // 8, np.uint8)
+        np.bitwise_or.at(self.mask, low >> 3, (1 << (low & 7)).astype(np.uint8))
+
+    def index(self, pos: np.ndarray) -> np.ndarray:
+        """Where each of ``pos`` is in ``ids``, or -1."""
+        low = pos & (MASK_BITS - 1)
+        hit = np.flatnonzero((self.mask[low >> 3] >> (low & 7).astype(np.uint8)) & 1)
+        g = np.minimum(np.searchsorted(self.ids, pos[hit]), len(self.ids) - 1)
+        out = np.full(len(pos), -1, np.int64)
+        out[hit] = np.where(self.ids[g] == pos[hit], g, -1)
+        return out
+
+
 class _Earliest:
     """Per bit id of interest (``ids``, sorted): the earliest ack of an
     insert RPC that set it, and the two earliest sends from different
-    RPCs, so that one RPC's own insert can be left out."""
+    RPCs, so that one RPC's own insert can be left out. What it holds
+    does not depend on how the inserts are split among calls to ``add``
+    or in which order they come."""
 
     def __init__(self, ids: np.ndarray):
         self.ids = ids
@@ -164,40 +207,38 @@ class _Earliest:
         self.ack = np.full(n, np.inf)
         self.send1, self.send2 = np.full(n, np.inf), np.full(n, np.inf)
         self.rpc1 = np.full(n, -1, np.int64)
-        # a bitmap of the ids modulo MASK_BITS: most bits of no interest
-        # are dropped by it before the exact search
-        low = ids & (MASK_BITS - 1)
-        self.mask = np.zeros(MASK_BITS // 8, np.uint8)
-        np.bitwise_or.at(self.mask, low >> 3, (1 << (low & 7)).astype(np.uint8))
 
-    def wanted(self, pos: np.ndarray) -> np.ndarray:
-        low = pos & (MASK_BITS - 1)
-        hit = np.flatnonzero((self.mask[low >> 3] >> (low & 7).astype(np.uint8)) & 1)
-        g = np.minimum(np.searchsorted(self.ids, pos[hit]), len(self.ids) - 1)
-        out = np.zeros(len(pos), bool)
-        out[hit] = self.ids[g] == pos[hit]
-        return out
-
-    def add(self, pos, ack, s1, r1, s2) -> None:
-        """Bits ``pos`` (all among ``ids``), each set by inserts that were
-        acknowledged at the earliest at ``ack``, first sent at ``s1`` by
-        RPC ``r1``, and sent at ``s2`` at the earliest by another RPC."""
-        g = np.searchsorted(self.ids, pos)
+    def add(self, g, ack, s1, r1, s2) -> None:
+        """Bits ``ids[g]``, each set by inserts that were acknowledged at
+        the earliest at ``ack``, first sent at ``s1`` by RPC ``r1``, and
+        sent at ``s2`` at the earliest by another RPC (``inf`` for one
+        insert)."""
+        if not len(g):
+            return
         o = np.lexsort((s1, g))
         g, ack, s1, r1, s2 = g[o], ack[o], s1[o], r1[o], s2[o]
         first = np.r_[True, g[1:] != g[:-1]]
         starts = np.flatnonzero(first)
         grp = np.cumsum(first) - 1
-        gu, b1, c1 = g[starts], s1[starts], r1[starts]
+        c1 = r1[starts]
         b2 = np.minimum.reduceat(np.where(r1 != c1[grp], s1, s2), starts)
-        self.ack[gu] = np.minimum(self.ack[gu], np.minimum.reduceat(ack, starts))
-        # merge (b1, c1, b2) with what is held
-        S1, R1, S2 = self.send1[gu], self.rpc1[gu], self.send2[gu]
+        self.merge(g[starts], np.minimum.reduceat(ack, starts), s1[starts], c1, b2)
+
+    def merge(self, g, ack, b1, c1, b2) -> None:
+        """Fold in, for bits ``ids[g]`` (``g`` unique), what another
+        ``_Earliest`` over the same ids holds (``held``)."""
+        self.ack[g] = np.minimum(self.ack[g], ack)
+        S1, R1, S2 = self.send1[g], self.rpc1[g], self.send2[g]
         held_first = S1 <= b1
-        self.send2[gu] = np.where(R1 == c1, np.minimum(S2, b2),
-                                  np.where(held_first, np.minimum(S2, b1), np.minimum(b2, S1)))
-        self.rpc1[gu] = np.where(held_first, R1, c1)
-        self.send1[gu] = np.minimum(S1, b1)
+        self.send2[g] = np.where(R1 == c1, np.minimum(S2, b2),
+                                 np.where(held_first, np.minimum(S2, b1), np.minimum(b2, S1)))
+        self.rpc1[g] = np.where(held_first, R1, c1)
+        self.send1[g] = np.minimum(S1, b1)
+
+    def held(self) -> tuple:
+        """What is held for each bit that an insert set, as ``merge`` takes it."""
+        g = np.flatnonzero(self.send1 < np.inf)
+        return g, self.ack[g], self.send1[g], self.rpc1[g], self.send2[g]
 
     def lookup(self, pos: np.ndarray, rpc: np.ndarray):
         """(earliest ack, earliest send by another RPC than ``rpc``)."""
@@ -206,7 +247,103 @@ class _Earliest:
         return self.ack[g], send
 
 
-def check(spec: Spec, gen, history: list, seed: int, sample: int) -> dict:
+def _by_key(ku8, lens, rpc, send, ack) -> tuple:
+    """One entry per distinct key (its padded bytes and its length): the
+    key, its earliest ack, and its two earliest sends by different RPCs,
+    the first with its RPC."""
+    words = np.ascontiguousarray(ku8).view(np.uint64) if ku8.shape[1] % 8 == 0 else ku8
+    words = np.concatenate([words, lens.astype(words.dtype)[:, None]], axis=1)
+    o = np.argsort(words[:, 0], kind="stable")
+    if np.any((words[o[1:], 0] == words[o[:-1], 0]) & np.any(words[o[1:]] != words[o[:-1]], axis=1)):
+        o = np.lexsort(tuple(words[:, j] for j in reversed(range(words.shape[1]))))
+    ku8, lens, rpc, send, ack, words = (x[o] for x in (ku8, lens, rpc, send, ack, words))
+    first = np.r_[True, np.any(words[1:] != words[:-1], axis=1)]
+    starts = np.flatnonzero(first)
+    grp = np.cumsum(first) - 1
+    s1 = np.minimum.reduceat(send, starts)
+    at = np.minimum.reduceat(np.where(send == s1[grp], np.arange(len(send)), len(send)), starts)
+    r1 = rpc[at]
+    s2 = np.minimum.reduceat(np.where(rpc != r1[grp], send, np.inf), starts)
+    return ku8[starts], lens[starts], np.minimum.reduceat(ack, starts), s1, r1, s2
+
+
+def _scan(spec: Spec, gen, blocks: np.ndarray, ids: _IdSet, cols: dict, lo: int, hi: int) -> tuple:
+    """``_Earliest.held`` of the inserts of insert RPCs ``lo:hi`` alone
+    (``cols``: each insert RPC's ``stream``, ``i``, ``n``, history index
+    ``rpc``, ``send`` and ``ack``), hashed in chunks of about CHUNK_KEYS
+    keys. Only keys in a block marked in ``blocks`` are hashed to their
+    bits, once per distinct key in a chunk."""
+    earliest = _Earliest(ids.ids)
+    ku8s, lenss, held = [], [], 0
+    for j in range(lo, hi):
+        keys = gen.keys_at(int(cols["stream"][j]), int(cols["i"][j]), np.arange(cols["n"][j]))
+        ku8, lens = pack(keys, spec.key_len, spec.key_policy)
+        ku8s.append(ku8)
+        lenss.append(lens)
+        held += len(lens)
+        if held < CHUNK_KEYS and j < hi - 1:
+            continue
+        r = np.repeat(np.arange(j + 1 - len(lenss), j + 1), [len(x) for x in lenss])
+        ku8, lens = np.concatenate(ku8s), np.concatenate(lenss)
+        ku8s, lenss, held = [], [], 0
+        blk = spec.block(ku8, lens)
+        sel = np.flatnonzero(blocks[blk])
+        if not len(sel):
+            continue
+        r = r[sel]
+        ku8, lens, a1, s1, r1, s2 = _by_key(ku8[sel], lens[sel], cols["rpc"][r], cols["send"][r], cols["ack"][r])
+        g = ids.index((spec.block(ku8, lens)[:, None] * spec.block_bits + spec.bits(ku8, lens)).ravel())
+        keep = g >= 0
+        earliest.add(g[keep], *(np.repeat(x, spec.k)[keep] for x in (a1, s1, r1, s2)))
+    return earliest.held()
+
+
+@functools.lru_cache(maxsize=1)
+def _load_task(path: str):
+    """A worker's inputs, written once by ``check`` (spawned workers
+    start from a fresh import)."""
+    with np.load(path) as z:
+        meta = json.loads(str(z["meta"]))
+        cols = {c: z[c] for c in COLUMNS}
+        blocks, ids = z["blocks"], z["ids"]
+    return Spec(meta["cfg"]), traffic.Generator(meta["mix"], meta["seed"]), blocks, _IdSet(ids), cols
+
+
+def _scan_shard(task):
+    path, lo, hi = task
+    return _scan(*_load_task(path), lo, hi)
+
+
+def _shards(n: np.ndarray, parts: int) -> list:
+    """``parts`` or fewer contiguous ranges of RPCs with about equal keys."""
+    ends = np.cumsum(n)
+    cuts = np.searchsorted(ends, ends[-1] * np.arange(1, parts) / parts) + 1
+    bounds = np.unique(np.r_[0, np.minimum(cuts, len(n)), len(n)])
+    return list(zip(bounds[:-1].tolist(), bounds[1:].tolist()))
+
+
+def _held_by_shard(spec: Spec, gen, blocks: np.ndarray, ids: np.ndarray, cols: dict, workers: int):
+    """``_scan`` of every insert RPC, in one contiguous shard per worker
+    process (in this process where ``workers`` is 1), in history order."""
+    count = len(cols["n"])
+    if workers <= 1 or count <= 1:
+        yield _scan(spec, gen, blocks, _IdSet(ids), cols, 0, count)
+        return
+    shards = _shards(cols["n"], workers)
+    with tempfile.TemporaryDirectory(prefix="tpubloom-check-") as tmp:
+        path = os.path.join(tmp, "task.npz")
+        meta = json.dumps({"cfg": spec.cfg, "mix": gen.mix, "seed": gen.seed})
+        np.savez(path, meta=np.array(meta), blocks=blocks, ids=ids, **cols)
+        # spawn, not fork: the caller may hold threads (gRPC's among them);
+        # a worker that dies breaks the pool, which raises, never hangs
+        pool = ProcessPoolExecutor(len(shards), mp_context=multiprocessing.get_context("spawn"))
+        try:
+            yield from pool.map(_scan_shard, [(path, lo, hi) for lo, hi in shards])
+        finally:
+            pool.shutdown(wait=True, cancel_futures=True)
+
+
+def check(spec: Spec, gen, history: list, seed: int, sample: int, workers: int | None = None) -> dict:
     """Compare a sample of the verdicts in ``history`` with the reference.
 
     ``history`` holds one dict per RPC: ``stream``, ``i``, ``op``, ``n``,
@@ -215,7 +352,9 @@ def check(spec: Spec, gen, history: list, seed: int, sample: int) -> dict:
     applied, so it counts as sent and never acknowledged. Returns the
     counts compared: ``missed`` (a key that must be present answered
     absent), ``phantom`` (a key that may not be present answered
-    present) and ``sampled``.
+    present) and ``sampled``. The inserted keys are hashed on
+    ``workers`` processes, by default one per usable core; the counts
+    do not depend on how many.
     """
     judged = [(n, h) for n, h in enumerate(history) if h["ok"] and h.get("verdict") is not None]
     sizes = np.array([len(h["verdict"]) for _, h in judged], np.int64)
@@ -227,9 +366,10 @@ def check(spec: Spec, gen, history: list, seed: int, sample: int) -> dict:
     ends = np.cumsum(sizes)
     which = np.searchsorted(ends, pick, side="right")
     keys, q_rpc, q_send, q_ack, q_v = [], [], [], [], []
-    for r in np.unique(which):
+    rpcs, firsts = np.unique(which, return_index=True)  # ``which`` is sorted
+    for r, lo, hi in zip(rpcs, firsts, np.r_[firsts[1:], len(which)]):
         hid, h = judged[r]
-        idx = pick[which == r] - (ends[r] - sizes[r])
+        idx = pick[lo:hi] - (ends[r] - sizes[r])
         keys.append(pack(gen.keys_at(h["stream"], h["i"], idx), spec.key_len, spec.key_policy))
         q_rpc.append(np.full(len(idx), hid))
         q_send.append(np.full(len(idx), h["send"], np.float64))
@@ -238,57 +378,20 @@ def check(spec: Spec, gen, history: list, seed: int, sample: int) -> dict:
     q_pos = spec.positions(np.concatenate([k for k, _ in keys]), np.concatenate([l for _, l in keys]))
     q_rpc, q_send, q_ack, q_v = map(np.concatenate, (q_rpc, q_send, q_ack, q_v))
     earliest = _Earliest(np.unique(q_pos))
-    wanted = np.zeros(spec.n_blocks * spec.shards, bool)
-    wanted[earliest.ids // spec.block_bits] = True
+    blocks = np.zeros(spec.n_blocks * spec.shards, bool)
+    blocks[earliest.ids // spec.block_bits] = True
 
-    # every inserted key in a sampled block: the bits it set, and when;
-    # RPCs are hashed in chunks of about CHUNK_KEYS keys
-    def flush(part):
-        ku8 = np.concatenate([p[0] for p in part])
-        lens = np.concatenate([p[1] for p in part])
-        blk = spec.block(ku8, lens)
-        sel = np.flatnonzero(wanted[blk])
-        ku8, lens, blk = ku8[sel], lens[sel], blk[sel]
-        rpc, send, ack = (np.concatenate([p[j] for p in part])[sel] for j in (2, 3, 4))
-        # one entry per distinct key: earliest ack, and the two earliest
-        # sends by different RPCs
-        # a key is its padded bytes and its length
-        words = np.ascontiguousarray(ku8).view(np.uint64) if ku8.shape[1] % 8 == 0 else ku8
-        words = np.concatenate([words, lens.astype(words.dtype)[:, None]], axis=1)
-        o = np.argsort(words[:, 0], kind="stable")
-        if np.any((words[o[1:], 0] == words[o[:-1], 0]) & np.any(words[o[1:]] != words[o[:-1]], axis=1)):
-            o = np.lexsort(tuple(words[:, j] for j in reversed(range(words.shape[1]))))
-        ku8, lens, blk, rpc, send, ack, words = (x[o] for x in (ku8, lens, blk, rpc, send, ack, words))
-        first = np.r_[True, np.any(words[1:] != words[:-1], axis=1)]
-        starts = np.flatnonzero(first)
-        if not len(starts):
-            return
-        grp = np.cumsum(first) - 1
-        s1 = np.minimum.reduceat(send, starts)
-        at = np.minimum.reduceat(np.where(send == s1[grp], np.arange(len(send)), len(send)), starts)
-        r1 = rpc[at]
-        s2 = np.minimum.reduceat(np.where(rpc != r1[grp], send, np.inf), starts)
-        a1 = np.minimum.reduceat(ack, starts)
-        ku8, lens, blk = ku8[starts], lens[starts], blk[starts]
-        pos = (blk[:, None] * spec.block_bits + spec.bits(ku8, lens)).ravel()
-        per = [np.repeat(x, spec.k) for x in (a1, s1, r1, s2)]
-        keep = earliest.wanted(pos)
-        earliest.add(pos[keep], *(x[keep] for x in per))
-
-    part, held = [], 0
-    for hid, h in enumerate(history):
-        if h["op"] not in ("insert", "insert_presence"):
-            continue
-        ku8, lens = pack(gen.keys_at(h["stream"], h["i"], np.arange(h["n"])), spec.key_len, spec.key_policy)
-        n = len(lens)
-        part.append((ku8, lens, np.full(n, hid), np.full(n, float(h["send"])),
-                     np.full(n, float(h["ack"]) if h["ok"] else np.inf)))
-        held += len(lens)
-        if held >= CHUNK_KEYS:
-            flush(part)
-            part, held = [], 0
-    if part:
-        flush(part)
+    # every bit of interest that an inserted key set, and when: each
+    # shard of the insert RPCs is folded alone, then merged here
+    ins = [hid for hid, h in enumerate(history) if h["op"] in traffic.INSERTING]
+    cols = {c: np.array([history[j][c] for j in ins], np.int64) for c in ("stream", "i", "n")}
+    cols["rpc"] = np.array(ins, np.int64)
+    cols["send"] = np.array([float(history[j]["send"]) for j in ins], np.float64)
+    cols["ack"] = np.array([float(history[j]["ack"]) if history[j]["ok"] else np.inf for j in ins], np.float64)
+    if workers is None:
+        workers = len(os.sched_getaffinity(0))
+    for held in _held_by_shard(spec, gen, blocks, earliest.ids, cols, workers):
+        earliest.merge(*held)
     k = q_pos.shape[1]
     acked, sent = earliest.lookup(q_pos.ravel(), np.repeat(q_rpc, k))
     must = acked.reshape(-1, k).max(axis=1) < q_send

@@ -360,6 +360,7 @@ def run(args) -> dict:
     clients = None
     try:
         server.wait_ready()
+        t_ready = time.monotonic_ns()
         device = server.cmd("device")
         say(f"device: {device}")
         if not args.rehearse_cpu and (device["platform"] != "tpu" or device["count"] < cell["chips"]):
@@ -369,6 +370,7 @@ def run(args) -> dict:
         with BloomClient(server.address, timeout=600.0) as c:
             c.create_filter(FILTER, config=cfg["filter"])
             warm = warm_launch_sizes(c, mix, args.seed)
+        t_warmed = time.monotonic_ns()
         clients = Clients(server, mix_path, mix, args.seed, workdir, env)
         clients.start()
         t_warm = time.monotonic()
@@ -411,9 +413,11 @@ def run(args) -> dict:
         time.sleep(max(0.0, (t1 - time.monotonic_ns()) / 1e9))
         c1 = server.cmd("compiles")
         scrape1 = server.scrape() if args.trace else None
+        t_closed = time.monotonic_ns()
         outs = clients.finish()
         memory = server.cmd("memory")
         server.stop()
+        t_stopped = time.monotonic_ns()
         say(f"compiles in the window: " + json.dumps({k: c1[k] - c0[k] for k in c1}))
         history, errors, hung = load_history(outs)
         traffic_errors = sum(not h["ok"] for h in history) + hung
@@ -430,10 +434,12 @@ def run(args) -> dict:
         # -- correctness: every answer came, with as many verdicts as keys,
         # and a sample of verdicts agrees with the reference
         short = sum(1 for h in history if h["ok"] and h["verdict"] is not None and len(h["verdict"]) != h["n"])
-        t_ref = time.monotonic()
+        t_ref = time.monotonic_ns()
         got = reference.check(reference.Spec(cfg["filter"]), traffic.Generator(mix, args.seed),
                               history, args.seed, int(mix["check_sample"]))
-        say(f"reference: {got['sampled']} verdicts checked in {time.monotonic() - t_ref:.1f} s")
+        t_checked = time.monotonic_ns()
+        say(f"reference: {got['sampled']} verdicts checked in {(t_checked - t_ref) / 1e9:.1f} s "
+            f"on {len(os.sched_getaffinity(0))} cores")
         checks = {
             "rpc_errors": (traffic_errors, 0),
             "short_answers": (short, 0),
@@ -444,6 +450,10 @@ def run(args) -> dict:
 
         dev = {"platform": device["platform"], "kind": device["kind"], "count": device["count"],
                "memory_peak_bytes": memory["peak"]}
+        # wall seconds of each stage of the run, so that a slow run shows where
+        stages = {"setup": t0 - T_START, "boot": t_ready - T_START, "launch_warmup": t_warmed - t_ready,
+                  "traffic_warmup": t0 - t_warmed, "window": t_closed - t0, "drain_stop": t_stopped - t_closed,
+                  "history": t_ref - t_stopped, "check": t_checked - t_ref}
         prefix = REHEARSAL_PREFIX if args.rehearse_cpu else ""
         metrics: dict = {}
         breakdown = None
@@ -454,6 +464,7 @@ def run(args) -> dict:
                     metrics[prefix + m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
         else:
             tr = reduce_trace(trace_dir, env)
+            stages["trace_reduce"] = time.monotonic_ns() - t_checked
             with open(os.path.join(HERE, "peaks.json")) as f:
                 peaks = json.load(f)
             if device["kind"] not in peaks and not args.rehearse_cpu:
@@ -476,6 +487,8 @@ def run(args) -> dict:
                     metrics[prefix + m["name"]] = {"value": val, "unit": m["unit"]}
             dev["busy_s"], dev["window_s"] = tr["busy_s"], tr["window_s"]
             breakdown = {"device_ops": tr["device_ops"][:10], "idle_gaps": tr["idle_gaps"][:10]}
+        stages["total"] = time.monotonic_ns() - T_START
+        say("stage seconds: " + json.dumps({k: round(v / 1e9, 3) for k, v in stages.items()}))
         result = {"correct": bool(correct), "attempted": w["rpcs"], "failed": w["failed"],
                   "metrics": metrics, "device": dev}
         if breakdown is not None:

@@ -1,8 +1,14 @@
-"""The copied reference agrees with ``tpubloom.cpu_ref``, and the
-ordering check accepts every serial history and catches wrong verdicts."""
+"""The copied reference agrees with ``tpubloom.cpu_ref``, the ordering
+check accepts every serial history and catches wrong verdicts, and it
+gives the same counts however many processes hash the inserted keys."""
+
+import json
+import os
 
 import numpy as np
 import pytest
+
+from conftest import HERE
 
 import reference
 import traffic
@@ -49,6 +55,20 @@ def test_pack_agrees_with_program_packing():
     got = reference.pack(keys, 128, "digest")
     np.testing.assert_array_equal(got[0], want[0])
     np.testing.assert_array_equal(got[1], want[1])
+
+
+@pytest.mark.parametrize("width", [4, 16, 20, 128])
+@pytest.mark.parametrize("lengths", ["full", "mixed"])
+def test_murmur3_agrees_with_program_hash(width, lengths):
+    """Keys that all fill the width take the reference's short cut;
+    keys of mixed lengths do not."""
+    rng = np.random.default_rng(width)
+    n = 3000
+    lens = np.full(n, width, np.int32) if lengths == "full" else rng.integers(0, width + 1, n).astype(np.int32)
+    ku8 = rng.integers(0, 256, (n, width), dtype=np.uint8)
+    ku8[np.arange(width)[None, :] >= lens[:, None]] = 0
+    for seed in (0, CFG["seed"] ^ SEED_XOR_ROUTE):
+        np.testing.assert_array_equal(reference.murmur3_32(ku8, lens, seed), murmur3_32_np(ku8, lens, seed))
 
 
 def test_shard_routing_agrees_with_program_hash():
@@ -107,3 +127,144 @@ def test_overlapping_rpcs_may_see_each_other():
     hist[1]["send"] = 120  # now sent after the first was acknowledged: must be present
     hist[1]["verdict"] = np.zeros(len(a), bool)
     assert reference.check(spec, gen, hist, 1, 3000)["missed"] > 0
+
+
+with open(os.path.join(HERE, "data", "configs", "tiny-sharded.json")) as f:
+    TINY_SHARDED = json.load(f)["filter"]
+DEDUP_MIX = {"mode": "unary", "processes": 1, "threads": 1, "batch": 300, "pattern": ["insert_presence"],
+             "keys": {"dist": "uniform", "len": 16}, "repeat": {"insert_presence": 0.5}, "repeat_lag": 1}
+
+
+def _bitset_history(spec, gen, n_rpcs, streams, *, overlap=0, fail=(), flip=0.0, seed=0):
+    """RPC ``k`` (callers in turn) is sent at ``10 k`` and acknowledged
+    ``5 + overlap`` later; verdicts come from a reference bitset applied
+    in send order, then a seeded share ``flip`` of them is inverted. The
+    RPCs numbered in ``fail`` failed: no verdict, and (odd ones only)
+    their keys were not applied."""
+    bits = np.zeros(spec.n_blocks * spec.shards * spec.block_bits, bool)
+    rng = np.random.default_rng(seed)
+    hist = []
+    for i in range(n_rpcs):
+        for s in range(streams):
+            k = len(hist)
+            op, keys = gen.rpc(s, i)
+            pos = spec.positions(*reference.pack(keys, spec.key_len, spec.key_policy))
+            v = bits[pos].all(axis=1) ^ (rng.random(len(keys)) < flip)
+            if op != "query" and not (k in fail and k % 2):
+                bits[pos.ravel()] = True
+            ok = k not in fail
+            hist.append({"stream": s, "i": i, "op": op, "send": 10 * k, "ack": 10 * k + 5 + overlap,
+                         "ok": ok, "n": len(keys), "verdict": v if ok else None})
+    return hist
+
+
+def _case(name):
+    """(spec, generator, history, seed, sample) of one equivalence case."""
+    if name == "serial-wrong":
+        gen = traffic.Generator(MIX, 8)
+        hist = _serial_history(gen, CFG, 8)
+        for h in hist[::3]:
+            h["verdict"] = ~h["verdict"]
+        return reference.Spec(CFG), gen, hist, 8, 4000
+    if name == "overlapping":
+        gen, spec = traffic.Generator(MIX, 21), reference.Spec(CFG)
+        return spec, gen, _bitset_history(spec, gen, 12, 2, overlap=25, flip=0.02, seed=1), 21, 3000
+    if name == "repeats-across-shards":
+        gen, spec = traffic.Generator(DEDUP_MIX, 2**31 + 77), reference.Spec(CFG)
+        return spec, gen, _bitset_history(spec, gen, 48, 1, flip=0.01, seed=2), 2**31 + 77, 5000
+    if name == "tiny-sharded":
+        mix = dict(DEDUP_MIX, batch=512, repeat={"insert_presence": 0.3})
+        gen, spec = traffic.Generator(mix, 5), reference.Spec(TINY_SHARDED)
+        return spec, gen, _bitset_history(spec, gen, 10, 2, overlap=12, flip=0.02, seed=3), 5, 3000
+    if name == "client-order":
+        # as a run records it: caller by caller, with the warm-up RPCs,
+        # sent first, at the end
+        mix = dict(DEDUP_MIX, batch=200)
+        gen, spec = traffic.Generator(mix, 31), reference.Spec(CFG)
+        hist = _bitset_history(spec, gen, 12, 3, overlap=40, flip=0.02, seed=6)
+        hist.sort(key=lambda h: (h["i"] == 0, h["stream"], h["i"]))
+        return spec, gen, hist, 31, 3000
+    if name == "failed-insert":
+        mix = dict(DEDUP_MIX, repeat={"insert_presence": 0.3})
+        gen, spec = traffic.Generator(mix, 13), reference.Spec(CFG)
+        hist = _bitset_history(spec, gen, 10, 2, fail=(4, 9), flip=0.01, seed=4)
+        for h in hist:
+            if not h["ok"]:
+                h["ack"] = 10**18  # never answered: the reference holds it as never acknowledged
+        return spec, gen, hist, 13, 3000
+    raise KeyError(name)
+
+
+# what the single-process check before the worker pool returned on each case
+PARENT_COUNTS = {
+    "serial-wrong": {"missed": 92, "phantom": 1388, "sampled": 4000},
+    "overlapping": {"missed": 3, "phantom": 74, "sampled": 3000},
+    "repeats-across-shards": {"missed": 15, "phantom": 30, "sampled": 5000},
+    "tiny-sharded": {"missed": 16, "phantom": 52, "sampled": 3000},
+    "failed-insert": {"missed": 8, "phantom": 26, "sampled": 3000},
+    "client-order": {"missed": 14, "phantom": 37, "sampled": 3000},
+}
+
+
+@pytest.mark.parametrize("chunk", [reference.CHUNK_KEYS, 97])
+@pytest.mark.parametrize("name", sorted(PARENT_COUNTS))
+def test_check_is_the_same_in_one_process_and_on_several(name, chunk, monkeypatch):
+    """The inserted-key pass in-process and on three workers, with the
+    keys hashed in-process in one chunk or in chunks of 97, gives the
+    counts the single-process check gave."""
+    monkeypatch.setattr(reference, "CHUNK_KEYS", chunk)
+    spec, gen, hist, seed, sample = _case(name)
+    one = reference.check(spec, gen, hist, seed, sample, workers=1)
+    several = reference.check(spec, gen, hist, seed, sample, workers=3)
+    assert one == several == PARENT_COUNTS[name]
+
+
+@pytest.mark.parametrize("mix", [
+    dict(MIX, keys={"dist": "zipf", "theta": 0.99, "items": 4096, "len": 16}),
+    dict(URL_MIX, pattern=["insert", "query"], repeat={"query": 0.5}, batch=500),
+])
+def test_worker_count_does_not_change_the_result(mix):
+    """Zipf keys (each worker rebuilds the generator's table) and long
+    URL keys (digested in each worker) on 1, 2 and 5 workers."""
+    cfg = URL_CFG if mix["keys"]["dist"] == "url" else CFG
+    gen, spec = traffic.Generator(mix, 99), reference.Spec(cfg)
+    hist = _bitset_history(spec, gen, 14, 3, overlap=15, flip=0.01, seed=5)
+    got = [reference.check(spec, gen, hist, 99, 2000, workers=w) for w in (1, 2, 5)]
+    assert got[0]["sampled"] == 2000 and got[0]["missed"] > 0
+    assert got[0] == got[1] == got[2]
+
+
+def test_shards_are_contiguous_and_cover_every_rpc():
+    n = np.array([300, 5, 5, 5, 1000, 300, 300, 2, 300])
+    shards = reference._shards(n, 4)
+    assert shards[0][0] == 0 and shards[-1][1] == len(n)
+    assert all(a[1] == b[0] for a, b in zip(shards, shards[1:]))
+    assert all(lo < hi for lo, hi in shards) and len(shards) <= 4
+    assert reference._shards(n[:1], 4) == [(0, 1)]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_earliest_does_not_depend_on_how_inserts_are_split(seed):
+    """Single inserts (bit, RPC, send, ack) with many ties, added at once,
+    in shuffled chunks, and folded apart per chunk then merged, agree
+    with the minima taken by brute force."""
+    rng = np.random.default_rng(seed)
+    n, ids = 400, np.arange(0, 120, 4)
+    g = rng.integers(0, len(ids), n)
+    rpc, send = rng.integers(0, 6, n), rng.integers(0, 10, n).astype(float)
+    ack = send + rng.integers(1, 5, n)
+    whole, split, merged = (reference._Earliest(ids) for _ in range(3))
+    whole.add(g, ack, send, rpc, np.full(n, np.inf))
+    for part in np.array_split(rng.permutation(n), 7):
+        split.add(g[part], ack[part], send[part], rpc[part], np.full(len(part), np.inf))
+        alone = reference._Earliest(ids)
+        alone.add(g[part], ack[part], send[part], rpc[part], np.full(len(part), np.inf))
+        merged.merge(*alone.held())
+    for r in range(7):
+        pos, who = ids, np.full(len(ids), r)
+        want_ack = [ack[g == b].min(initial=np.inf) for b in range(len(ids))]
+        want_send = [send[(g == b) & (rpc != r)].min(initial=np.inf) for b in range(len(ids))]
+        for e in (whole, split, merged):
+            got_ack, got_send = e.lookup(pos, who)
+            np.testing.assert_array_equal(got_ack, want_ack)
+            np.testing.assert_array_equal(got_send, want_send)

@@ -59,6 +59,21 @@ def test_traced_rehearsal_reads_per_layer_metrics():
     assert len(r["breakdown"]["device_ops"]) <= 10
 
 
+def test_stage_seconds_come_before_the_result_line():
+    """The run names the wall seconds of each of its stages on stdout,
+    and the result stays the last line."""
+    p, r = run("tiny-dedup", seed=2**33 + 28)
+    assert p.returncode == 0, p.stderr[-3000:]
+    lines = p.stdout.strip().splitlines()
+    stage = [ln for ln in lines if ln.startswith("bench: stage seconds: ")]
+    assert len(stage) == 1 and lines.index(stage[0]) < len(lines) - 1 and r["correct"] is True
+    stages = json.loads(stage[0][len("bench: stage seconds: "):])
+    assert list(stages) == ["setup", "boot", "launch_warmup", "traffic_warmup", "window", "drain_stop",
+                            "history", "check", "total"]
+    assert all(v >= 0 for v in stages.values()) and stages["window"] >= 3
+    assert stages["total"] >= stages["setup"] + stages["window"] + stages["check"]
+
+
 def test_sharded_rehearsal_on_four_virtual_devices():
     p, r = run("tiny-sharded-dedup", env_extra=FOUR)
     assert p.returncode == 0, p.stderr[-3000:]
